@@ -60,6 +60,8 @@ def main() -> None:
                          "defaults to BENCH_kernels.json on FULL runs only "
                          "— a --only run would clobber it with partial rows")
     args = ap.parse_args()
+    from repro.compile_cache import use_checkout_cache
+    use_checkout_cache()
     if args.json is None:
         args.json = "" if args.only else "BENCH_kernels.json"
     print("name,us_per_call,derived")

@@ -631,6 +631,11 @@ class _BitplaneVarReader:
         for s, k in zip(self.streams, self._plane_targets(eps)):
             if s.fetch_to_planes(k):
                 self._dirty = True
+        return self.current()
+
+    def current(self) -> Tuple[np.ndarray, float]:
+        """Reconstruction at the present plane counts and its certified
+        bound — fetches nothing."""
         if self.var.method in ("hb", "ip"):
             self._refresh_hb_incremental()
         else:
@@ -990,6 +995,13 @@ class RetrievalSession:
                 self._mask_charged[name] = True
             data = mask.apply(data)
         return data, achieved
+
+    def current(self, name: str) -> Tuple[np.ndarray, float]:
+        """The variable as this session's last ``reconstruct`` left it, with
+        its certified bound — moves no bytes (bitplane archives only)."""
+        data, achieved = self.readers[name].current()
+        mask = self.archive.masks.get(name)
+        return (data if mask is None else mask.apply(data)), achieved
 
     def reconstruct_at_resolution(self, name: str, coarsen: int,
                                   eps: float) -> Tuple[np.ndarray, float]:

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.bitplane_pack import interpret_default
+
 DEFAULT_ROWS = 8
 
 
@@ -33,9 +35,12 @@ def _kernel(even_ref, odd_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def hier_level_surplus(x_even: jnp.ndarray, x_odd: jnp.ndarray,
                        rows: int = DEFAULT_ROWS,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """x_even: (B, M+1) coarse nodes, x_odd: (B, M) new nodes, B % rows == 0.
-    Returns (B, M) surpluses."""
+    Returns (B, M) surpluses.  ``interpret=None`` auto-detects the backend
+    (compile on TPU)."""
+    if interpret is None:
+        interpret = interpret_default()
     b, m = x_odd.shape
     if x_even.shape != (b, m + 1):
         raise ValueError(f"even {x_even.shape} vs odd {x_odd.shape}")

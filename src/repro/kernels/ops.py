@@ -1,9 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python for correctness validation. On a real TPU backend
-``interpret`` flips to False automatically and the same BlockSpecs drive
-Mosaic compilation.
+On CPU the kernels execute in interpret mode — the kernel body runs in
+Python for correctness validation. On a TPU backend ``interpret`` flips to
+False automatically and the same BlockSpecs drive Mosaic compilation.
 
 Codec dispatch policy: the pack/unpack wrappers pick geometry per backend —
 on TPU the canonical 8-row tiles (VMEM-sized, grid-parallel); in interpret
@@ -23,11 +22,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.bitplane_pack import (
+    BITS,
     bitplane_pack,
     interpret_default as _interpret,
     pack_planes_traced,
+    tile_elems,
 )
-from repro.kernels.bitplane_unpack import WORDS_PER_ROW, bitplane_unpack
+from repro.kernels.bitplane_unpack import bitplane_unpack
 from repro.kernels.hier_level import hier_level_surplus
 from repro.kernels.qoi_vtotal import qoi_vtotal_fused
 
@@ -109,16 +110,22 @@ def pack_bitplanes(mag: jnp.ndarray, nbits: int = 30,
     mag = jnp.asarray(mag, jnp.int32)
     interp = _interpret()
     if rows is None:
-        if interp:
-            padded, n = _pad_to(mag, LANES)
-            rows = padded.shape[0] // LANES      # one whole-array tile
-        else:
-            rows = 8
-            padded, n = _pad_to(mag, rows * LANES)
+        padded, n, rows = _pack_tiles(mag, interp)
     else:
-        padded, n = _pad_to(mag, rows * LANES)
+        padded, n = _pad_to(mag, tile_elems(rows))
     out = bitplane_pack(padded, nbits=nbits, rows=rows, interpret=interp)
     return out[:, : (n + 31) // 32]
+
+
+def _pack_tiles(x: jnp.ndarray, interp: bool):
+    """Pad (N,) for the pack kernel and pick its tile rows: the canonical
+    8-row tiles on TPU, one whole-array tile in interpret mode.  Returns
+    ``(padded, N, rows)``."""
+    if interp:
+        padded, n = _pad_to(x, tile_elems(1))
+        return padded, n, padded.shape[0] // tile_elems(1)
+    padded, n = _pad_to(x, tile_elems(8))
+    return padded, n, 8
 
 
 @functools.partial(jax.jit, static_argnames=("nbits", "rows", "interpret"))
@@ -145,12 +152,7 @@ def encode_magnitude_planes(c: np.ndarray, scale: float,
     runs as a single fused jit dispatch; only zlib stays on the host."""
     c = jnp.asarray(c, jnp.float64)
     interp = _interpret()
-    if interp:
-        padded, n = _pad_to(c, LANES)
-        rows = padded.shape[0] // LANES      # one whole-array tile
-    else:
-        rows = 8
-        padded, n = _pad_to(c, rows * LANES)
+    padded, n, rows = _pack_tiles(c, interp)
     out = _encode_planes_fused(padded, jnp.float64(scale), nbits=nbits,
                                rows=rows, interpret=interp)
     return np.asarray(out)[:, : (n + 31) // 32]
@@ -192,21 +194,23 @@ def unpack_bitplanes(words, shifts, count: int) -> np.ndarray:
 
 
 def _unpack_kernel_u64(words: np.ndarray, shifts: np.ndarray,
-                       count: int, rows: int = 8) -> np.ndarray:
+                       count: int) -> np.ndarray:
     """TPU path: split planes into hi (shift >= 32) / lo words, one kernel
-    call each, recombine into uint64 magnitudes."""
+    call each, recombine into uint64 magnitudes.  Each call's plane count is
+    padded to a power of two with zero planes (exact no-ops) so the kernel
+    compiles for a bounded set of shapes across fetch windows."""
     out = np.zeros(count, dtype=np.uint64)
     hi = shifts >= 32
     for sel, base in ((hi, 32), (~hi, 0)):
         if not np.any(sel):
             continue
         w = words[sel]
-        pad = (-w.shape[1]) % (rows * WORDS_PER_ROW)
-        if pad:
-            w = np.pad(w, ((0, 0), (0, pad)))
-        grp = bitplane_unpack(jnp.asarray(w),
-                              jnp.asarray(shifts[sel] - base, jnp.uint32),
-                              rows=rows)
+        sh = shifts[sel] - base
+        p_pad = _plane_pad(w.shape[0])
+        if p_pad != w.shape[0]:
+            w = np.pad(w, ((0, p_pad - w.shape[0]), (0, 0)))
+            sh = np.pad(sh, (0, p_pad - sh.shape[0]))
+        grp = bitplane_unpack(jnp.asarray(w), jnp.asarray(sh, jnp.int32))
         out |= np.asarray(grp, dtype=np.uint64)[:count] << np.uint64(base)
     return out
 
@@ -222,12 +226,17 @@ def _decode_fused_body(words, shifts, state, sign_bytes, scale):
     decoder.
     """
     nplanes, nwords = words.shape
-    mag = state
-    bit_idx = jnp.arange(32, dtype=jnp.uint32)
-    for j in range(nplanes):                               # static unroll
+    bit_idx = jnp.arange(BITS, dtype=jnp.uint32)
+
+    def plane(j, mag):
         bits = (words[j][:, None] >> bit_idx) & jnp.uint32(1)
-        mag = mag | (bits.reshape(nwords * 32).astype(jnp.uint64)
-                     << shifts[j])
+        return mag | (bits.reshape(nwords * BITS).astype(jnp.uint64)
+                      << shifts[j])
+
+    # a loop, not a static unroll: the working set stays one plane's bits
+    # whatever the plane-slot count (an unrolled 64-slot decode over an
+    # archival-size group does not fit a 16 GB chip)
+    mag = jax.lax.fori_loop(0, nplanes, plane, state)
     sbits = (sign_bytes[:, None]
              >> jnp.arange(7, -1, -1, dtype=jnp.uint8)) & jnp.uint8(1)
     signs = sbits.reshape(nwords * 32).astype(bool)

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.bitplane_pack import interpret_default
+
 LANES = 128
 DEFAULT_ROWS = 8
 
@@ -45,9 +47,12 @@ def _kernel(vx_ref, vy_ref, vz_ref, eps_ref, val_ref, bound_ref):
 @functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def qoi_vtotal_fused(vx: jnp.ndarray, vy: jnp.ndarray, vz: jnp.ndarray,
                      eps: jnp.ndarray, rows: int = DEFAULT_ROWS,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """vx/vy/vz: (N,) with N % (rows*128) == 0; eps: (3,) per-variable bounds.
-    Returns (val, bound), each (N,)."""
+    Returns (val, bound), each (N,).  ``interpret=None`` auto-detects the
+    backend (compile on TPU)."""
+    if interpret is None:
+        interpret = interpret_default()
     n = vx.shape[0]
     if n % (rows * LANES):
         raise ValueError(f"N={n} must be a multiple of rows*128={rows * LANES}")

@@ -18,22 +18,13 @@ PEAK_FLOPS_BF16 = 197e12        # FLOP/s
 HBM_BW = 819e9                  # B/s
 ICI_BW = 50e9                   # B/s per link
 
-# jax >= 0.5 moved explicit/auto axis semantics into make_mesh(axis_types=);
-# on 0.4.x the kwarg (and jax.sharding.AxisType) does not exist and every
-# axis is implicitly Auto — which is the only type this codebase uses.
-_AXIS_TYPE_AUTO = getattr(jax.sharding, "AxisType", None)
-_AXIS_TYPE_AUTO = getattr(_AXIS_TYPE_AUTO, "Auto", None)
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """Version-portable ``jax.make_mesh`` with all axes of type Auto."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _AXIS_TYPE_AUTO is not None:
-        kwargs["axis_types"] = (_AXIS_TYPE_AUTO,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

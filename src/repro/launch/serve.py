@@ -50,6 +50,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.bitplane import codecs as plane_codecs
+from repro.compile_cache import use_checkout_cache
 from repro.core import ge
 from repro.core.refactor import ContribStats, refactor_variables
 from repro.core.retrieval import QoIRequest, retrieve_qoi_controlled
@@ -333,6 +334,7 @@ def main(argv=None) -> int:
                          f"{','.join(plane_codecs.DEFAULT_CANDIDATES)}; "
                          "raw is always implied)")
     args = ap.parse_args(argv)
+    use_checkout_cache()
     if args.codecs is not None:
         plane_codecs.set_default_candidates(
             n for n in args.codecs.split(",") if n)

@@ -10,8 +10,8 @@ AdamW/Adafactor, gradient clipping, optional bitplane gradient compression
 (--resume), and deterministic synthetic data. On a TPU cluster the same
 driver runs under the production mesh (launch/mesh.py); flags documented
 for latency hiding on real backends:
-  LIBTPU_INIT_ARGS=--xla_tpu_enable_async_collective_fusion=true
-  --xla_tpu_enable_async_collective_fusion_fuse_all_gather=true
+  LIBTPU_INIT_ARGS="$LIBTPU_INIT_ARGS --xla_tpu_enable_async_collective_fusion=true
+  --xla_tpu_enable_async_collective_fusion_fuse_all_gather=true"
 """
 from __future__ import annotations
 

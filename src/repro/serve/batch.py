@@ -114,6 +114,10 @@ class DecodeBatcher:
         # the vmapped graph set stays tiny; archives with more planes than
         # this keep their natural power-of-two padded length
         self.plane_slots = int(plane_slots)
+        # device working set one vmapped dispatch may take: a third of the
+        # device's memory; buckets past it split into power-of-two chunks
+        # (None where the backend reports no limit: the CPU)
+        self.max_batch_bytes = _device_batch_budget()
         self.stats = BatcherStats()
         self._mu = threading.Lock()
         self._pending: List[Ticket] = []
@@ -167,18 +171,28 @@ class DecodeBatcher:
         for t in batch:
             buckets.setdefault(t.key, []).append(t)
         dispatches = 0
-        for key, tickets in buckets.items():
-            try:
-                if key[0] == "decode":
-                    dispatches += self._run_decode(tickets)
-                else:
-                    dispatches += self._run_recompose(tickets)
-            except BaseException as e:   # propagate to every waiter
-                for t in tickets:
-                    t._finish(error=e)
+        for key, bucket in buckets.items():
+            for tickets in self._chunks(bucket):
+                try:
+                    if key[0] == "decode":
+                        dispatches += self._run_decode(tickets)
+                    else:
+                        dispatches += self._run_recompose(tickets)
+                except BaseException as e:   # propagate to every waiter
+                    for t in tickets:
+                        t._finish(error=e)
         with self.stats._mu:
             self.stats.flushes += 1
         return dispatches
+
+    def _chunks(self, tickets: List[Ticket]) -> List[List[Ticket]]:
+        """Split one bucket so each vmapped dispatch — padded to a power
+        of two — stays within ``max_batch_bytes`` (at least one item)."""
+        if self.max_batch_bytes is None or len(tickets) < 2:
+            return [tickets]
+        fit = max(1, self.max_batch_bytes // _item_bytes(tickets[0]))
+        size = 1 << (fit.bit_length() - 1)          # largest pow2 <= fit
+        return [tickets[i:i + size] for i in range(0, len(tickets), size)]
 
     @staticmethod
     def _pad_pow2(items: List) -> List:
@@ -255,3 +269,27 @@ class DecodeBatcher:
         for i, t in enumerate(tickets):
             t._finish(out[i])
         return 1
+
+
+def _device_batch_budget() -> Optional[int]:
+    """A third of the default device's memory, or None where the backend
+    reports no limit (the CPU)."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) // 3 if limit else None
+
+
+def _item_bytes(t: Ticket) -> int:
+    """Device bytes one item adds to a vmapped dispatch — arguments,
+    results and temporaries, bounding what the v5e compiler's memory
+    analysis counts at archival sizes (tests/test_tpu_compile.py): a decode
+    item holds its plane words plus at most eight magnitude-length uint64
+    arrays (state in and out, values, per-plane bits); a recompose item
+    its index and value vectors plus at most six field-sized f64 arrays
+    (the device pads the field's minor dimensions to its tiling)."""
+    if t.kind == "decode":
+        w, _, st, sb = t.payload[:4]
+        return int(w.nbytes + sb.nbytes + 8 * st.nbytes)
+    idx, vals, shape = t.payload[:3]
+    return int(idx.nbytes + vals.nbytes + 6 * 8 * int(np.prod(shape)))

@@ -37,9 +37,10 @@ def _plane_bytes(pattern: str, n: int, density: float, seed: int) -> bytes:
         bits = (np.arange(n * 8) % 2).astype(bool)
     else:  # "bursty": zero stretches broken by dense bursts
         bits = np.zeros(n * 8, dtype=bool)
-        for _ in range(max(1, n // 64)):
+        for _ in range(max(1, n // 64) if n else 0):
             s = int(rng.integers(0, max(1, n * 8 - 32)))
-            bits[s:s + 32] = rng.random(32) < 0.8
+            e = min(s + 32, bits.size)       # planes under 32 bits: clip
+            bits[s:e] = rng.random(e - s) < 0.8
     return np.packbits(bits).tobytes()
 
 

@@ -836,3 +836,39 @@ def test_batcher_straggler_shapes_dispatch_solo():
             want = decode_values(lbp, decode_magnitudes(lbp, 17))
             assert np.array_equal(want.view(np.uint64),
                                   vals.view(np.uint64))
+
+
+def test_batcher_splits_buckets_past_max_batch_bytes():
+    """A bucket whose vmapped dispatch would exceed the batcher's device
+    budget splits into power-of-two chunks — here 3 same-shape items under
+    a budget that fits two become one batched pair plus one solo dispatch —
+    and every item still decodes bit-identically."""
+    from repro.bitplane.encoder import (decode_magnitudes, decode_values,
+                                        encode_level, inflate_planes,
+                                        sign_plane_bytes)
+    from repro.serve import DecodeBatcher
+    from repro.serve.batch import _item_bytes
+
+    rng = np.random.default_rng(8)
+    lbps = [encode_level(rng.standard_normal(400)) for _ in range(3)]
+
+    def submit(bat, lbp):
+        words, shifts = inflate_planes(lbp.count, lbp.nbits,
+                                       lbp.planes[:17], 0)
+        return bat.submit_decode(words, shifts, None,
+                                 sign_plane_bytes(lbp.count, lbp.signs),
+                                 np.float64(2.0) ** (lbp.exponent - lbp.nbits),
+                                 lbp.count)
+
+    probe = DecodeBatcher(window_ms=0.0)
+    item = _item_bytes(submit(probe, lbps[0]))
+    bat = DecodeBatcher(window_ms=0.0)
+    bat.max_batch_bytes = 2 * item + 1
+    tickets = [submit(bat, lbp) for lbp in lbps]
+    assert bat.flush() == 2
+    st = bat.stats.as_dict()
+    assert st["decode_items"] == 3 and st["decode_batched"] == 2
+    for lbp, t in zip(lbps, tickets):
+        want = decode_values(lbp, decode_magnitudes(lbp, 17))
+        got = np.asarray(t.result()[1])
+        assert np.array_equal(want.view(np.uint64), got.view(np.uint64))
