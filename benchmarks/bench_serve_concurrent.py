@@ -115,8 +115,7 @@ class _MiniServer:
         if workers is not None:
             self.plane = ServePlane(self.handle, workers=workers,
                                     queue_depth=4 * N_CLIENTS,
-                                    session_key=lambda r: r[0],
-                                    decode_batcher=decode_batcher)
+                                    session_key=lambda r: r[0])
 
     def handle(self, req):
         client, var, eps = req

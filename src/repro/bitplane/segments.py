@@ -32,6 +32,7 @@ from repro.bitplane.encoder import (
     values_from_planes,
 )
 from repro.kernels import ops
+from repro.trace import INFLATE, note_h2d, span, to_host
 
 
 class _Ready:
@@ -103,12 +104,13 @@ class LevelStream:
     """Progressive reader state over one group's PlaneSource."""
 
     def __init__(self, source: Union[PlaneSource, LevelBitplanes],
-                 batcher=None):
+                 batcher=None, xfer_stats=None):
         if isinstance(source, LevelBitplanes):
             source = InMemoryPlaneSource(source)
         self.source = source
         self.meta = source.meta
         self.batcher = batcher        # serve.DecodeBatcher or None
+        self.xfer_stats = xfer_stats  # trace.TransferStats or None
         self.fetched = 0
         self.bytes_fetched = 0
         # degraded mode: deepest reachable plane count once a segment of
@@ -165,14 +167,17 @@ class LevelStream:
                 # sign + scale to one fused device dispatch at flush time;
                 # byte accounting above is already settled, so deferral
                 # never changes FetchStats
-                words, shifts = inflate_planes(meta.count, meta.nbits,
-                                               blobs, self.fetched)
+                with span(INFLATE):
+                    words, shifts = inflate_planes(meta.count, meta.nbits,
+                                                   blobs, self.fetched)
                 self._pending_words.append(words)
                 self._pending_shifts.append(shifts)
             else:
-                self._mag = accumulate_planes(meta.count, meta.nbits, blobs,
-                                              self.fetched,
-                                              state=self._host_mag())
+                state = self._host_mag()
+                with span(INFLATE):
+                    self._mag = accumulate_planes(meta.count, meta.nbits,
+                                                  blobs, self.fetched,
+                                                  state=state)
             self.fetched = got
             self.bytes_fetched += new_bytes
             self._values = None
@@ -241,6 +246,9 @@ class LevelStream:
         self._pending_shifts.clear()
         scale = np.float64(2.0) ** (meta.exponent - meta.nbits)
         sb = self._decoded_signs()
+        # the payload as handed over, before the decode pads its planes
+        note_h2d(self.xfer_stats, words, shifts, sb,
+                 *([self._mag] if isinstance(self._mag, np.ndarray) else []))
         if self.batcher is not None:
             return self.batcher.submit_decode(words, shifts, self._mag, sb,
                                               scale, meta.count)
@@ -275,7 +283,8 @@ class LevelStream:
             else:
                 self._flush()
                 if self._values_dev is not None:
-                    self._values = np.asarray(self._values_dev)
+                    self._values = to_host(self._values_dev,
+                                           self.xfer_stats)
                 else:
                     self._values = values_from_planes(
                         self.meta.count, self.meta.exponent, self.meta.nbits,

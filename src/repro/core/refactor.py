@@ -81,6 +81,7 @@ from repro.compressors.snapshots import (
 )
 from repro.core.masks import OutlierMask, build_zero_velocity_mask
 from repro.options import SessionOptions, _from_legacy
+from repro.trace import READER_REFRESH, RECONSTRUCT, note_h2d, span, to_host
 from repro.transform.hierarchical import (
     decompose_hb,
     grid_levels,
@@ -229,7 +230,8 @@ class BitplaneVarArchive:
         return _BitplaneVarReader(
             self, contrib_budget_bytes=opts.contrib_budget_bytes,
             contrib_pool=opts.contrib_pool,
-            decode_batcher=opts.decode_batcher)
+            decode_batcher=opts.decode_batcher,
+            xfer_stats=opts.xfer_stats)
 
 
 @dataclass
@@ -412,13 +414,19 @@ class _BitplaneVarReader:
     mutation moves under the pool's lock so cross-session reclaim is
     race-free.  Spill/recompute semantics — and bit-identical outputs —
     are unchanged; only WHICH levels stay resident becomes dynamic.
+
+    ``xfer_stats`` (a :class:`repro.trace.TransferStats`) counts the bytes
+    the reader and its streams move between host and device.
     """
 
     def __init__(self, var, contrib_budget_bytes: Optional[int] = None,
-                 contrib_stats=None, contrib_pool=None, decode_batcher=None):
+                 contrib_stats=None, contrib_pool=None, decode_batcher=None,
+                 xfer_stats=None):
         self.var = var
         self._batcher = decode_batcher
-        self.streams = [LevelStream(src, batcher=decode_batcher)
+        self._xfer = xfer_stats
+        self.streams = [LevelStream(src, batcher=decode_batcher,
+                                    xfer_stats=xfer_stats)
                         for src in var.plane_sources()]
         self._idx_dev: Dict[int, object] = {}   # device group_indices cache
         self._recon: Optional[np.ndarray] = None
@@ -637,7 +645,8 @@ class _BitplaneVarReader:
         """Reconstruction at the present plane counts and its certified
         bound — fetches nothing."""
         if self.var.method in ("hb", "ip"):
-            self._refresh_hb_incremental()
+            with span(READER_REFRESH):
+                self._refresh_hb_incremental()
         else:
             self._refresh_full()
         return self._recon, self.achieved_bound()
@@ -658,6 +667,7 @@ class _BitplaneVarReader:
         idx = self._idx_dev.get(l)
         if idx is None:
             import jax.numpy as jnp
+            note_h2d(self._xfer, self.var.group_indices[l])
             idx = self._idx_dev[l] = jnp.asarray(self.var.group_indices[l])
         return idx
 
@@ -690,9 +700,9 @@ class _BitplaneVarReader:
     def _contrib_collect(self, l: int, handle) -> np.ndarray:
         kind, h = handle
         if kind == "ticket":
-            return np.asarray(h.result())
+            return to_host(h.result(), self._xfer)
         if kind == "array":
-            return np.asarray(h)
+            return to_host(h, self._xfer)
         # host route: scatter on host, partial recompose on device — the
         # recompose graph is shared with the device route, so both are
         # bit-identical (pinned by tests/test_decode_conformance.py)
@@ -707,13 +717,16 @@ class _BitplaneVarReader:
             # ``scatter_recompose_ip_from``
             t = trunc_to_quantum(vals, self._ip_quantum(l))
             flat[idx] = t
-            out = np.array(recompose_hb_from(flat.reshape(shape), levels,
-                                             start))
+            note_h2d(self._xfer, flat)
+            out = np.array(to_host(recompose_hb_from(flat.reshape(shape),
+                                                     levels, start),
+                                   self._xfer))
             out.ravel()[idx] += vals - t
             return out
         flat[idx] = vals
-        return np.asarray(recompose_hb_from(flat.reshape(shape), levels,
-                                            start))
+        note_h2d(self._xfer, flat)
+        return to_host(recompose_hb_from(flat.reshape(shape), levels, start),
+                       self._xfer)
 
     def _compute_contrib(self, l: int) -> np.ndarray:
         """Contribution of group ``l``: its decoded values scattered onto the
@@ -889,6 +902,7 @@ class RetrievalSession:
         self.options = opts
         self.contrib_budget_bytes = opts.contrib_budget_bytes
         self.contrib_pool = opts.contrib_pool
+        self.xfer_stats = opts.xfer_stats
         self.coalescer = None
         self.readers: Dict[str, object] = {}
         self._mask_charged: Dict[str, bool] = {}
@@ -984,16 +998,17 @@ class RetrievalSession:
         ``coalescer`` attached (serve plane), concurrent duplicate requests
         across sessions collapse into one fetch + recompose — bit-identical
         results by the plane-count invariant."""
-        if self.coalescer is not None:
-            data, achieved = self.coalescer.reconstruct(self, name, eps)
-        else:
-            data, achieved = self.reader(name).request(eps)
-        mask = self.archive.masks.get(name)
-        if mask is not None:
-            if not self._mask_charged[name]:
-                self._mask_bytes += mask.nbytes
-                self._mask_charged[name] = True
-            data = mask.apply(data)
+        with span(RECONSTRUCT):
+            if self.coalescer is not None:
+                data, achieved = self.coalescer.reconstruct(self, name, eps)
+            else:
+                data, achieved = self.reader(name).request(eps)
+            mask = self.archive.masks.get(name)
+            if mask is not None:
+                if not self._mask_charged[name]:
+                    self._mask_bytes += mask.nbytes
+                    self._mask_charged[name] = True
+                data = mask.apply(data)
         return data, achieved
 
     def current(self, name: str) -> Tuple[np.ndarray, float]:

@@ -41,6 +41,7 @@ of evicting hot MSB prefixes moments before they are needed again).
 from __future__ import annotations
 
 import argparse
+import itertools
 import os
 import tempfile
 import time
@@ -66,6 +67,7 @@ from repro.store.httpd import StoreHTTPServer
 from repro.store.writer import ensure_archive   # noqa: F401  (re-export: the
 # create-once lockfile dance now lives with the writer API, but this module
 # remains its historical import path for embedders and tests)
+from repro.trace import REQUEST, TransferStats, compiles, span
 
 
 @dataclass
@@ -107,6 +109,10 @@ class RetrievalServer:
                  decode_batch_ms: Optional[float] = None):
         import threading
         t0 = time.time()
+        self.xfer = TransferStats()
+        self._seq = itertools.count()
+        self._rounds = 0               # Alg-2 rounds of every request
+        self._rounds_mu = threading.Lock()
         self.cache: Optional[SegmentCache] = None
         self.contrib_budget_bytes = contrib_budget_bytes
         self.contrib_pool = ContribBudgetPool(contrib_pool_bytes) \
@@ -145,8 +151,8 @@ class RetrievalServer:
         self.qois = ge.all_qois()
         self.plane = ServePlane(self._handle, workers=workers,
                                 queue_depth=queue_depth,
-                                session_key=lambda req: req.client,
-                                decode_batcher=self.decode_batcher)
+                                session_key=lambda req: req.client)
+        self._compiles0 = compiles().snapshot()   # counted from here on
 
     # -- request path --------------------------------------------------------
 
@@ -159,7 +165,8 @@ class RetrievalServer:
                 session = self.archive.open(SessionOptions(
                     contrib_budget_bytes=self.contrib_budget_bytes,
                     contrib_pool=self.contrib_pool,
-                    decode_batcher=self.decode_batcher))
+                    decode_batcher=self.decode_batcher,
+                    xfer_stats=self.xfer))
                 session.coalescer = self.coalescer
                 self.sessions[client] = session
         return session
@@ -168,11 +175,15 @@ class RetrievalServer:
         """One request, run inline on the calling thread (the worker body;
         also the sequential baseline the concurrency bench compares
         against).  Per-session serialization is the ServePlane's job."""
-        session = self._session(req.client)
-        before = session.bytes_retrieved
-        reqs = [QoIRequest(q, self.qois[q], req.tau) for q in req.qois]
-        t0 = time.time()
-        res = retrieve_qoi_controlled(session, reqs)
+        with span(REQUEST, client=req.client, seq=next(self._seq),
+                  tau=req.tau):
+            session = self._session(req.client)
+            before = session.bytes_retrieved
+            reqs = [QoIRequest(q, self.qois[q], req.tau) for q in req.qois]
+            t0 = time.time()
+            res = retrieve_qoi_controlled(session, reqs)
+        with self._rounds_mu:
+            self._rounds += len(res.iterations)
         return {"client": req.client, "tau": req.tau,
                 "bytes_moved": session.bytes_retrieved - before,
                 "bitrate": res.bitrate, "latency_s": time.time() - t0,
@@ -203,12 +214,20 @@ class RetrievalServer:
 
     def metrics(self) -> Dict[str, float]:
         """One flat counter dict for /metrics: pool, coalescer, budget
-        pool, segment cache, fetcher (transport + contrib + fault
-        counters) — everything a dashboard needs to see a multi-tenant
-        server breathe."""
+        pool, decode batcher, segment cache, fetcher (transport + contrib +
+        fault counters), host-device transfers, Alg-2 rounds and the
+        backend compiles since this server was made — everything a
+        dashboard needs to see a multi-tenant server breathe."""
         out = {f"serve_{k}": v for k, v in self.plane.metrics().items()}
         with self._sessions_mu:
             out["serve_sessions_sticky"] = float(len(self.sessions))
+        for k, v in self.xfer.as_dict().items():
+            out[f"xfer_{k}"] = v
+        with self._rounds_mu:
+            out["retrieval_iterations_total"] = float(self._rounds)
+        n, s = compiles().snapshot()
+        out["compiles_total"] = float(n - self._compiles0[0])
+        out["compile_seconds_total"] = s - self._compiles0[1]
         if self.coalescer is not None:
             for k, v in self.coalescer.metrics().items():
                 out[f"coalesce_{k}"] = v

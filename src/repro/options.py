@@ -143,12 +143,16 @@ class SessionOptions:
       * ``decode_batcher`` — shared :class:`repro.serve.batch.DecodeBatcher`
         merging this session's fused decode / recompose dispatches with
         every other session's into one vmapped device call per serve tick
-        (None = per-reader dispatch; results are bit-identical either way).
+        (None = per-reader dispatch; results are bit-identical either way);
+      * ``xfer_stats`` — :class:`repro.trace.TransferStats` counting the
+        bytes this session moves between host and device (None = not
+        counted).
     """
     prefetch_depth: int = 1
     contrib_budget_bytes: Optional[int] = None
     contrib_pool: Optional[Any] = None
     decode_batcher: Optional[Any] = None
+    xfer_stats: Optional[Any] = None
 
     @classmethod
     def default(cls) -> "SessionOptions":

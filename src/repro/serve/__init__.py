@@ -30,8 +30,7 @@ untouched except for the borrow/adopt hooks in ``core/refactor.py``.
 from repro.serve.batch import BatcherStats, DecodeBatcher
 from repro.serve.budget import ContribBudgetPool, PoolStats
 from repro.serve.coalesce import CoalesceStats, ReconstructCoalescer
-from repro.serve.metrics import (LatencyHistogram, MetricsRegistry,
-                                 render_metrics)
+from repro.serve.metrics import LatencyHistogram, render_metrics
 from repro.serve.pool import ServePlane, ServerOverloadedError
 
 __all__ = [
@@ -42,7 +41,6 @@ __all__ = [
     "CoalesceStats",
     "ReconstructCoalescer",
     "LatencyHistogram",
-    "MetricsRegistry",
     "render_metrics",
     "ServePlane",
     "ServerOverloadedError",

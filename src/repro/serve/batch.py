@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.kernels import ops
+from repro.trace import BATCH_FLUSH, BATCH_WINDOW, span
 
 
 @dataclass
@@ -94,9 +95,12 @@ class Ticket:
     def result(self):
         # first waiter gives the window a chance to fill, then drains the
         # whole pending set itself; later waiters usually find _done set
-        if not self._done.wait(self._batcher.window_s):
+        with span(BATCH_WINDOW):
+            done = self._done.wait(self._batcher.window_s)
+        if not done:
             self._batcher.flush()
-            self._done.wait()
+            with span(BATCH_WINDOW):      # another thread's flush of it
+                self._done.wait()
         if self._error is not None:
             raise self._error
         return self._result
@@ -171,16 +175,17 @@ class DecodeBatcher:
         for t in batch:
             buckets.setdefault(t.key, []).append(t)
         dispatches = 0
-        for key, bucket in buckets.items():
-            for tickets in self._chunks(bucket):
-                try:
-                    if key[0] == "decode":
-                        dispatches += self._run_decode(tickets)
-                    else:
-                        dispatches += self._run_recompose(tickets)
-                except BaseException as e:   # propagate to every waiter
-                    for t in tickets:
-                        t._finish(error=e)
+        with span(BATCH_FLUSH, items=len(batch)):
+            for key, bucket in buckets.items():
+                for tickets in self._chunks(bucket):
+                    try:
+                        if key[0] == "decode":
+                            dispatches += self._run_decode(tickets)
+                        else:
+                            dispatches += self._run_recompose(tickets)
+                    except BaseException as e:   # propagate to every waiter
+                        for t in tickets:
+                            t._finish(error=e)
         with self.stats._mu:
             self.stats.flushes += 1
         return dispatches

@@ -4,14 +4,15 @@ The /metrics endpoint is a plaintext ``name value`` dump (one counter per
 line, sorted) — the lowest-common-denominator format every scraper can
 ingest and every human can ``curl``.  Latency quantiles come from a
 log-bucketed histogram rather than a reservoir: fixed memory, lock-cheap
-increments, and the p50/p99 estimates stay within one bucket width (~7%)
-of the true quantile, which is plenty for tail-amplification reporting.
+increments, and the p50/p99 estimates stay within one bucket width of the
+true quantile — a bucket's upper edge is 1.25x its lower edge, so up to
+25% high — which is plenty for tail-amplification reporting.
 """
 from __future__ import annotations
 
 import math
 import threading
-from typing import Callable, Dict, List, Tuple
+from typing import Dict
 
 # Buckets span 10us .. ~167s at x1.25 steps: 1.25^72 ~= 9.3e6, i.e. enough
 # resolution for sub-ms cache hits and patience for WAN-bound tail requests.
@@ -84,42 +85,6 @@ class LatencyHistogram:
             "p99_ms": self.quantile(0.99) * 1e3,
             "max_ms": mx * 1e3,
         }
-
-
-class MetricsRegistry:
-    """Aggregates counter *sources* into one flat ``/metrics`` view.
-
-    A source is a zero-arg callable returning ``{name: number}``; the serve
-    plane registers one per subsystem (pool, coalescer, budget pool, cache,
-    fetcher, httpd) so the endpoint needs no knowledge of any of them.
-    Collisions are a programming error and raise at render time — silent
-    last-writer-wins would corrupt dashboards invisibly.
-    """
-
-    def __init__(self) -> None:
-        self._mu = threading.Lock()
-        self._sources: List[Tuple[str, Callable[[], Dict[str, float]]]] = []
-
-    def register(self, prefix: str,
-                 source: Callable[[], Dict[str, float]]) -> None:
-        with self._mu:
-            self._sources.append((prefix, source))
-
-    def collect(self) -> Dict[str, float]:
-        with self._mu:
-            sources = list(self._sources)
-        out: Dict[str, float] = {}
-        for prefix, source in sources:
-            for name, value in source().items():
-                key = f"{prefix}_{name}" if prefix else name
-                if key in out:
-                    raise ValueError(f"duplicate metric {key!r}")
-                out[key] = float(value)
-        return out
-
-    def render(self) -> str:
-        """Plaintext dump: one ``name value`` per line, sorted by name."""
-        return render_metrics(self.collect())
 
 
 def render_metrics(values: Dict[str, float]) -> str:

@@ -56,19 +56,13 @@ class ServePlane:
 
     def __init__(self, handler: Callable, workers: int = 8,
                  queue_depth: int = 64,
-                 session_key: Optional[Callable] = None,
-                 decode_batcher=None):
+                 session_key: Optional[Callable] = None):
         if workers < 1:
             raise ValueError("workers must be >= 1")
         if queue_depth < 1:
             raise ValueError("queue_depth must be >= 1")
         self.workers = int(workers)
         self.queue_depth = int(queue_depth)
-        # optional serve.DecodeBatcher the handlers share: worker threads
-        # flushing fused decodes within one window merge into a single
-        # vmapped dispatch (the "batched tick"); kept here so the pool's
-        # metrics() reports dispatch coalescing next to queue pressure
-        self.decode_batcher = decode_batcher
         self._handler = handler
         self._session_key = session_key or (
             lambda req: getattr(req, "client", None))
@@ -161,9 +155,6 @@ class ServePlane:
             out[f"queue_wait_{name}"] = value
         for name, value in self.handle_latency.snapshot().items():
             out[f"latency_{name}"] = value
-        if self.decode_batcher is not None:
-            for name, value in self.decode_batcher.stats.as_dict().items():
-                out[f"batch_{name}"] = value
         return out
 
     def shutdown(self, wait: bool = True) -> None:

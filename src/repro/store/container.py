@@ -379,7 +379,8 @@ class StoreBitplaneVar:
             self, contrib_budget_bytes=opts.contrib_budget_bytes,
             contrib_stats=self._fetcher.stats,
             contrib_pool=opts.contrib_pool,
-            decode_batcher=opts.decode_batcher)
+            decode_batcher=opts.decode_batcher,
+            xfer_stats=opts.xfer_stats)
 
 
 class _SnapshotHandle:

@@ -71,6 +71,7 @@ from repro.store.retry import (
     RetryPolicy,
     is_transient,
 )
+from repro.trace import STORE_READ, span
 
 
 class ChecksumError(IOError):
@@ -256,8 +257,9 @@ class SegmentFetcher:
                 with self._lock:
                     self.stats.cache_hits += 1
                 return buf
-        buf = self._store_for(entry.blob).read(entry.offset, entry.size)
-        self._verify(key, entry, buf)
+        with span(STORE_READ):
+            buf = self._store_for(entry.blob).read(entry.offset, entry.size)
+            self._verify(key, entry, buf)
         cname = codec_name(entry.codec)
         with self._lock:
             self.stats.bytes_fetched += entry.size
@@ -369,23 +371,28 @@ class SegmentFetcher:
         if not misses:
             return out
         blob = self.index[misses[0]].blob
-        try:
-            store = self._store_for(blob)
-            bufs = store.read_batch([(self.index[k].offset,
-                                      self.index[k].size) for k in misses])
-        except BaseException as e:          # transport-level: whole batch
-            for k in misses:
-                out[k] = e
-            return out
+        with span(STORE_READ):
+            try:
+                store = self._store_for(blob)
+                bufs = store.read_batch([(self.index[k].offset,
+                                          self.index[k].size)
+                                         for k in misses])
+            except BaseException as e:      # transport-level: whole batch
+                for k in misses:
+                    out[k] = e
+                return out
+            verified = []
+            for k, buf in zip(misses, bufs):
+                try:
+                    self._verify(k, self.index[k], buf)
+                except BaseException as e:  # this segment only
+                    out[k] = e
+                    continue
+                verified.append((k, buf))
         ok_bytes = ok_reads = 0
         ok_codec: Dict[str, int] = {}
-        for k, buf in zip(misses, bufs):
+        for k, buf in verified:
             entry = self.index[k]
-            try:
-                self._verify(k, entry, buf)
-            except BaseException as e:      # this segment only
-                out[k] = e
-                continue
             out[k] = buf
             ok_bytes += entry.size
             ok_reads += 1

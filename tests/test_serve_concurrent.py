@@ -772,17 +772,14 @@ def test_batched_tick_bit_identical_to_per_reader(vel):
                         for v in names]
 
             with ServePlane(handle, workers=len(reqs), queue_depth=16,
-                            session_key=lambda r: r[0],
-                            decode_batcher=bat) as plane:
+                            session_key=lambda r: r[0]) as plane:
                 futs = [plane.submit(r) for r in reqs]
                 got = {r[0]: f.result(120) for r, f in zip(reqs, futs)}
-                pm = plane.metrics()
         st = bat.stats.as_dict()
         assert st["decode_batched"] >= 2       # same-shape groups coalesced
         # the straggler's unique-shape groups fell back to solo dispatches
         assert st["decode_items"] > st["decode_batched"]
         assert st["decode_dispatches"] < st["decode_items"]
-        assert pm["batch_decode_items"] == st["decode_items"]
         # per-reader reference: fresh fused sessions WITHOUT a batcher issue
         # one dispatch per group flush; results must match bit-for-bit
         for client, names in reqs:
@@ -794,6 +791,30 @@ def test_batched_tick_bit_identical_to_per_reader(vel):
                 assert want_bound == bound
     finally:
         ops.set_decode_path(prev)
+
+
+def test_server_metrics_report_the_batcher_once(tmp_path):
+    """/metrics carries the shared decode batcher's counters once, under
+    the ``batch_*`` names, and the pool adds no second copy."""
+    from repro.kernels import ops
+
+    prev = ops.set_decode_path("fused")
+    try:
+        server = RetrievalServer(_vel_fields(), method="hb",
+                                 store_path=str(tmp_path / "v.prs"),
+                                 workers=2, decode_batch_ms=5.0)
+        try:
+            futs = [server.submit(Request(client=f"c{i}", qois=["VTOT"],
+                                          tau=1e-3)) for i in range(2)]
+            assert all(f.result(120)["guaranteed"] for f in futs)
+            m = server.metrics()
+            st = server.decode_batcher.stats.as_dict()
+        finally:
+            server.close()
+    finally:
+        ops.set_decode_path(prev)
+    assert m["batch_decode_items"] == st["decode_items"] > 0
+    assert not [k for k in m if k.startswith("serve_batch_")]
 
 
 def test_batcher_straggler_shapes_dispatch_solo():
