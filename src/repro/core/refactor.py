@@ -672,7 +672,7 @@ class _BitplaneVarReader:
         return idx
 
     def _contrib_submit(self, l: int):
-        """Phase 1 of a contribution rebuild: route the scatter+recompose to
+        """Phase 1 of a contribution rebuild: route the placement+recompose to
         the device when the stream holds device-resident decoded values
         (fused path), queueing on the shared DecodeBatcher when one is
         attached so same-shape rebuilds across readers merge into one
@@ -683,19 +683,19 @@ class _BitplaneVarReader:
         vals_dev = self.streams[l].values_device()
         if vals_dev is None:
             return ("host", None)
-        idx = self._group_idx_dev(l)
         if self.var.method == "ip":
+            idx = self._group_idx_dev(l)
             q = self._ip_quantum(l)
             if self._batcher is not None:
                 return ("ticket", self._batcher.submit_recompose(
-                    idx, vals_dev, shape, levels, start, quantum=q))
+                    vals_dev, shape, levels, start, quantum=q, idx=idx))
             return ("array", scatter_recompose_ip_from(idx, vals_dev, shape,
                                                        levels, start, q))
         if self._batcher is not None:
             return ("ticket", self._batcher.submit_recompose(
-                idx, vals_dev, shape, levels, start))
-        return ("array", scatter_recompose_from(idx, vals_dev, shape,
-                                                levels, start))
+                vals_dev, shape, levels, start))
+        return ("array", scatter_recompose_from(vals_dev, shape, levels,
+                                                start))
 
     def _contrib_collect(self, l: int, handle) -> np.ndarray:
         kind, h = handle
@@ -703,9 +703,9 @@ class _BitplaneVarReader:
             return to_host(h.result(), self._xfer)
         if kind == "array":
             return to_host(h, self._xfer)
-        # host route: scatter on host, partial recompose on device — the
-        # recompose graph is shared with the device route, so both are
-        # bit-identical (pinned by tests/test_decode_conformance.py)
+        # host route: scatter on host, partial recompose on device — bit-
+        # identical to the device route's placement + recompose (pinned by
+        # tests/test_decode_conformance.py, tests/test_recompose_reference.py)
         shape, levels = self.var.padded_shape, self.var.levels
         idx = self.var.group_indices[l]
         vals = self.streams[l].values()

@@ -11,7 +11,7 @@ each dispatch alone.  ``DecodeBatcher`` closes that gap:
   * the FIRST waiter sleeps one batching window (``window_ms``) and then
     drains everything pending, bucketing by dispatch shape —
     ``("decode", P_pad, W)`` for plane flushes and
-    ``("recompose", shape, levels, start, n_idx, is_ip)`` for
+    ``("recompose", shape, levels, start, n_vals, is_ip)`` for
     contributions (hb and `ip` items recompose through different graphs,
     so they never share a bucket; an ip item's quantum is a traced operand
     and does not split buckets);
@@ -144,17 +144,18 @@ class DecodeBatcher:
             self._pending.append(t)
         return t
 
-    def submit_recompose(self, idx, vals, shape: Tuple[int, ...],
-                         levels: int, start: int,
-                         quantum: Optional[float] = None) -> Ticket:
-        """Queue one contribution scatter+recompose
+    def submit_recompose(self, vals, shape: Tuple[int, ...], levels: int,
+                         start: int, quantum: Optional[float] = None,
+                         idx=None) -> Ticket:
+        """Queue one contribution placement+recompose
         (``transform.hierarchical.scatter_recompose_from``).  A non-None
         ``quantum`` routes through the `ip` variant
-        (``scatter_recompose_ip_from``) — the quantum itself is a traced
+        (``scatter_recompose_ip_from``), which also takes the group's node
+        indices ``idx`` for its tail — the quantum itself is a traced
         operand, so ip items with different quanta still share a bucket;
         only the hb/ip graph split keys the bucket."""
         key = ("recompose", tuple(shape), int(levels), int(start),
-               int(len(idx)), quantum is not None)
+               int(len(vals)), quantum is not None)
         t = Ticket(self, "recompose", key,
                    (idx, vals, tuple(shape), int(levels), int(start),
                     quantum))
@@ -251,8 +252,7 @@ class DecodeBatcher:
             for t in tickets:
                 idx, vals, shape, levels, start, quantum = t.payload
                 if quantum is None:
-                    t._finish(scatter_recompose_from(jnp.asarray(idx),
-                                                     jnp.asarray(vals),
+                    t._finish(scatter_recompose_from(jnp.asarray(vals),
                                                      shape, levels, start))
                 else:
                     t._finish(scatter_recompose_ip_from(
@@ -261,12 +261,11 @@ class DecodeBatcher:
             return n
         _, _, shape, levels, start, quantum = tickets[0].payload
         padded = self._pad_pow2(tickets)
-        idx_b = jnp.stack([jnp.asarray(t.payload[0]) for t in padded])
         vals_b = jnp.stack([jnp.asarray(t.payload[1]) for t in padded])
         if quantum is None:
-            out = scatter_recompose_from_batch(idx_b, vals_b, shape, levels,
-                                               start)
+            out = scatter_recompose_from_batch(vals_b, shape, levels, start)
         else:
+            idx_b = jnp.stack([jnp.asarray(t.payload[0]) for t in padded])
             q_b = jnp.asarray([t.payload[5] for t in padded],
                               dtype=jnp.float64)
             out = scatter_recompose_ip_from_batch(idx_b, vals_b, shape,
@@ -291,10 +290,12 @@ def _item_bytes(t: Ticket) -> int:
     analysis counts at archival sizes (tests/test_tpu_compile.py): a decode
     item holds its plane words plus at most eight magnitude-length uint64
     arrays (state in and out, values, per-plane bits); a recompose item
-    its index and value vectors plus at most six field-sized f64 arrays
+    its value vector (an `ip` item its index vector too) plus at most six
+    field-sized f64 arrays
     (the device pads the field's minor dimensions to its tiling)."""
     if t.kind == "decode":
         w, _, st, sb = t.payload[:4]
         return int(w.nbytes + sb.nbytes + 8 * st.nbytes)
     idx, vals, shape = t.payload[:3]
-    return int(idx.nbytes + vals.nbytes + 6 * 8 * int(np.prod(shape)))
+    idx_nbytes = 0 if idx is None else idx.nbytes
+    return int(idx_nbytes + vals.nbytes + 6 * 8 * int(np.prod(shape)))

@@ -16,6 +16,14 @@ paper exploits to fix PMGARD's over-retrieval (Fig 3).
 
 Grids are padded per-dimension to 2^k + 1 (edge-replicate); the padded
 surpluses are ~0 and compress away.
+
+The recompose carries the recomposed coarse grid from step to step and reads
+each level's coefficients as a strided slice: ``cur = where(mask, view +
+interp_up(cur), interp_up(cur))``.  Every interleave is a stack and a reshape
+and every placement static slices, so the device programs hold no scatter (a
+TPU runs one close to serially).  The result is bitwise the in-place
+write-back ``c[::s] = where(mask, view + pred, view)``: each value is the
+same operation on the same operands (see ``_recompose_steps``).
 """
 from __future__ import annotations
 
@@ -97,17 +105,26 @@ def _v2(idx: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def _interleave(even: Array, odd: Array, ax: int) -> Array:
+    """``out[2i] = even[i]``, ``out[2i+1] = odd[i]`` along ``ax`` (``even``
+    one longer than ``odd``), built from a stack and a reshape: every value
+    is moved, none is combined, so signed zeros and NaNs pass unchanged
+    (a strided ``.at[].set`` lowers to a TPU scatter)."""
+    n = even.shape[ax]
+    head = jax.lax.slice_in_dim(even, 0, n - 1, axis=ax)
+    last = jax.lax.slice_in_dim(even, n - 1, n, axis=ax)
+    pairs = jnp.stack([head, odd], axis=ax + 1)
+    pairs = pairs.reshape(even.shape[:ax] + (2 * (n - 1),)
+                          + even.shape[ax + 1:])
+    return jax.lax.concatenate([pairs, last], ax)
+
+
 def _up_axis(c: Array, ax: int) -> Array:
     """Linear-interpolate a (2m+1 -> from m+1) refinement along one axis."""
     n = c.shape[ax]
-    out_shape = c.shape[:ax] + (2 * n - 1,) + c.shape[ax + 1:]
     lo = jax.lax.slice_in_dim(c, 0, n - 1, axis=ax)
     hi = jax.lax.slice_in_dim(c, 1, n, axis=ax)
-    mid = 0.5 * (lo + hi)
-    out = jnp.zeros(out_shape, c.dtype)
-    even = tuple(slice(None) if i != ax else slice(0, None, 2) for i in range(c.ndim))
-    odd = tuple(slice(None) if i != ax else slice(1, None, 2) for i in range(c.ndim))
-    return out.at[even].set(c).at[odd].set(mid)
+    return _interleave(c, 0.5 * (lo + hi), ax)
 
 
 def interp_up(coarse: Array) -> Array:
@@ -151,22 +168,45 @@ def decompose_hb(x: Array, levels: int) -> Array:
     return x
 
 
-def _recompose_steps(c: Array, start: int) -> Array:
-    """Recompose steps start..0 (coarse -> fine), shared by the full and
-    partial entry points so both produce bitwise-identical op graphs."""
-    for l in range(start, -1, -1):
-        s = 1 << l
-        view = c[_view_slices(c.ndim, s)]
-        pred = interp_up(view[_view_slices(c.ndim, 2)])
+def _strided(c: Array, s: int) -> Array:
+    """The stride-``s`` view of ``c`` as one strided ``lax.slice`` (jnp's
+    ``[::s]`` lowers to gathers on TPU)."""
+    return jax.lax.slice(c, (0,) * c.ndim, c.shape, (s,) * c.ndim)
+
+
+def _recompose_steps(cur: Array, views: List[Array]) -> Array:
+    """Recompose steps coarse -> fine in carry form.  ``cur`` is the
+    recomposed grid one level coarser than ``views[0]``; ``views`` are the
+    coefficient field's strided views, coarsest first.  Each step predicts
+    the finer grid and adds the view's surpluses at its new nodes:
+
+        cur = where(mask, view + interp_up(cur), interp_up(cur))
+
+    This is bitwise the in-place write-back ``c[::s] = where(mask, view +
+    pred, view)``: at the coarse nodes the write-back keeps the already
+    recomposed values, which ``interp_up`` copies exactly, and at the new
+    nodes both add the same two operands.  ``view + pred`` stays an add even
+    where the coefficients are zero, because ``0.0 + -0.0`` is ``+0.0``."""
+    for view in views:
+        pred = interp_up(cur)
         mask = jnp.asarray(_new_node_mask(view.shape))
-        c = c.at[_view_slices(c.ndim, s)].set(jnp.where(mask, view + pred, view))
-    return c
+        cur = jnp.where(mask, view + pred, pred)
+    return cur
+
+
+def _recompose_field(c: Array, start: int) -> Array:
+    """Steps start..0 of a whole coefficient field (none for start < 0)."""
+    if start < 0:
+        return c
+    return _recompose_steps(_strided(c, 2 << start),
+                            [_strided(c, 1 << l)
+                             for l in range(start, -1, -1)])
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
 def recompose_hb(c: Array, levels: int) -> Array:
     """Inverse of decompose_hb; must run coarse -> fine."""
-    return _recompose_steps(c, levels - 1)
+    return _recompose_field(c, levels - 1)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -177,37 +217,92 @@ def recompose_hb_from(c: Array, levels: int, start: int) -> Array:
     see an all-zero view and are exact no-ops — while costing only the fine
     half of the step ladder.  This is what makes per-level incremental
     reconstruction (core/refactor.py) both cheap and reproducible."""
-    return _recompose_steps(c, min(start, levels - 1))
+    return _recompose_field(c, min(start, levels - 1))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def scatter_recompose_from(idx: Array, vals: Array,
-                           shape: Tuple[int, ...], levels: int,
+def _view_shape(shape: Tuple[int, ...], s: int) -> Tuple[int, ...]:
+    return tuple(-(-n // s) for n in shape)
+
+
+def _place_new_nodes(vals: Array, shape: Tuple[int, ...]) -> Array:
+    """Rows of ``vals`` (B, count) hold, in C order, the new nodes
+    (``_new_node_mask``) of a grid of odd extents ``shape``; returns
+    (B, *shape) with them in place and zeros at the coarse nodes.  Along
+    the first axis the new nodes alternate between an even plane's own new
+    nodes and a whole odd plane, so static slices and reshapes split them,
+    the even planes recurse on the remaining axes, and one interleave per
+    axis puts them back together."""
+    b = vals.shape[0]
+    m, rest = shape[0] // 2, shape[1:]
+    full = int(np.prod(rest, dtype=np.int64))
+    sub = full - int(np.prod([(n + 1) // 2 for n in rest], dtype=np.int64))
+    head = vals[:, :m * (sub + full)].reshape(b, m, sub + full)
+    odd = head[:, :, sub:].reshape((b, m) + rest)
+    if rest:
+        last = vals[:, None, m * (sub + full):]
+        even = jnp.concatenate([head[:, :, :sub], last], axis=1)
+        even = _place_new_nodes(even.reshape(b * (m + 1), sub), rest)
+        even = even.reshape((b, m + 1) + rest)
+    else:
+        even = jnp.zeros((b, m + 1), vals.dtype)
+    return _interleave(even, odd, 1)
+
+
+def _zero_view(dtype, shape: Tuple[int, ...]) -> Array:
+    """A zero coefficient view the compiler cannot see to be zero: it folds
+    ``x + 0.0`` into ``x``, which would keep a ``-0.0`` that the sum with a
+    stored zero field turns into ``+0.0``."""
+    zero = jax.lax.optimization_barrier(jnp.zeros((), dtype))
+    return jnp.broadcast_to(zero, shape)
+
+
+def _recompose_group(vals: Array, shape: Tuple[int, ...], levels: int,
+                     start: int) -> Array:
+    """Partial recompose of one coefficient group, placed without a scatter.
+    A detail group ``l`` (``start == l``) is, in C order, exactly the new
+    nodes of the stride-2^l view; the base group (``start == levels - 1``)
+    is the whole stride-2^levels grid that step ``start`` refines.  The
+    field is zero everywhere else, so every other view is zero — bitwise
+    what ``recompose_hb_from`` computes on the scattered field."""
+    start = min(start, levels - 1)
+    if start < 0:                      # no levels: the group is the field
+        return vals.reshape(shape)
+    coarse = _view_shape(shape, 2 << start)
+    fine = [_view_shape(shape, 1 << l) for l in range(start, -1, -1)]
+    finer = [_zero_view(vals.dtype, f) for f in fine[1:]]
+    n_coarse = int(np.prod(coarse))
+    if vals.shape[0] == n_coarse:      # the base group
+        return _recompose_steps(vals.reshape(coarse),
+                                [_zero_view(vals.dtype, fine[0])] + finer)
+    if vals.shape[0] != int(np.prod(fine[0])) - n_coarse:
+        raise ValueError(f"{vals.shape[0]} values are no coefficient group "
+                         f"of {shape} from step {start}")
+    placed = _place_new_nodes(vals[None], fine[0])[0]
+    return _recompose_steps(_zero_view(vals.dtype, coarse), [placed] + finer)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def scatter_recompose_from(vals: Array, shape: Tuple[int, ...], levels: int,
                            start: int) -> Array:
-    """Scatter one level's coefficient values into a zero field and partially
-    recompose it — the device-resident form of the reader's per-level
-    contribution (core/refactor.py::_compute_contrib).  ``idx`` holds flat
-    node indices, ``vals`` the decoded coefficients (straight off the fused
-    decode, no host round-trip).  The scatter is exact placement and the
-    recompose graph is shared with ``recompose_hb_from``, so the result is
-    bit-identical to the host scatter + recompose pair."""
-    field = jnp.zeros(int(np.prod(shape)), dtype=vals.dtype)
-    field = field.at[idx].set(vals).reshape(shape)
-    return _recompose_steps(field, min(start, levels - 1))
+    """One level's coefficient values, partially recomposed onto the padded
+    grid — the device-resident form of the reader's per-level contribution
+    (core/refactor.py::_compute_contrib).  ``vals`` are the decoded
+    coefficients (straight off the fused decode, no host round-trip) in the
+    group's C order, placed by static slices and interleaves, so the result
+    is bit-identical to the host scatter + ``recompose_hb_from`` pair."""
+    return _recompose_group(vals, shape, levels, start)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def scatter_recompose_from_batch(idx: Array, vals: Array,
-                                 shape: Tuple[int, ...], levels: int,
-                                 start: int) -> Array:
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def scatter_recompose_from_batch(vals: Array, shape: Tuple[int, ...],
+                                 levels: int, start: int) -> Array:
     """vmapped ``scatter_recompose_from`` over a leading batch axis: one
     dispatch recomposes the same-shaped contribution of B readers (the serve
     plane's batched tick).  vmap only adds the batch dimension — each slice
     runs the identical elementwise graph, so results match the per-reader
     dispatch bit-for-bit."""
     return jax.vmap(
-        lambda i, v: scatter_recompose_from(i, v, shape, levels, start)
-    )(idx, vals)
+        lambda v: scatter_recompose_from(v, shape, levels, start))(vals)
 
 
 def hb_error_bound(level_bounds: List[float]) -> float:
@@ -259,18 +354,17 @@ def scatter_recompose_ip_from(idx: Array, vals: Array,
                               shape: Tuple[int, ...], levels: int,
                               start: int, quantum: Array) -> Array:
     """`ip` counterpart of ``scatter_recompose_from``: truncate the decoded
-    values to the group's prediction quantum, scatter + partially recompose
+    values to the group's prediction quantum, place + partially recompose
     the truncated part (the closed-loop prediction seed for finer groups),
-    then add the truncation tail back at the group's own nodes.  ``quantum``
-    is a traced operand (2^{E-kp}, or 0.0 for no truncation) so one compiled
-    graph serves every group of a given geometry."""
+    then scatter-add the truncation tail back at the group's own nodes
+    ``idx``.  ``quantum`` is a traced operand (2^{E-kp}, or 0.0 for no
+    truncation) so one compiled graph serves every group of a given
+    geometry."""
     q = jnp.asarray(quantum, dtype=vals.dtype)
     safe = jnp.where(q == 0.0, jnp.asarray(1.0, vals.dtype), q)
     t = jnp.where(q == 0.0, vals,
                   jnp.sign(vals) * jnp.floor(jnp.abs(vals) / safe) * safe)
-    field = jnp.zeros(int(np.prod(shape)), dtype=vals.dtype)
-    field = field.at[idx].set(t).reshape(shape)
-    out = _recompose_steps(field, min(start, levels - 1))
+    out = _recompose_group(t, shape, levels, start)
     return out.reshape(-1).at[idx].add(vals - t).reshape(shape)
 
 

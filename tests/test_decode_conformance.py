@@ -356,17 +356,14 @@ def test_scatter_recompose_matches_host_scatter():
         flat[idx] = vals
         host = np.asarray(recompose_hb_from(flat.reshape(shape), levels,
                                             start))
-        dev = np.asarray(scatter_recompose_from(jnp.asarray(idx),
-                                                jnp.asarray(vals), shape,
+        dev = np.asarray(scatter_recompose_from(jnp.asarray(vals), shape,
                                                 levels, start))
         assert np.array_equal(_bits(host), _bits(dev)), l
         singles.append((start, host))
-    # batch variant: duplicate one level's scatter across a batch axis
+    # batch variant: duplicate one level's contribution across a batch axis
     start, host = singles[0]
-    idx0 = jnp.asarray(var.group_indices[0])
     vals0 = jnp.asarray(reader.streams[0].values())
-    out = scatter_recompose_from_batch(jnp.stack([idx0, idx0]),
-                                       jnp.stack([vals0, vals0]), shape,
+    out = scatter_recompose_from_batch(jnp.stack([vals0, vals0]), shape,
                                        levels, start)
     for b in range(2):
         assert np.array_equal(_bits(np.asarray(out[b])), _bits(host))

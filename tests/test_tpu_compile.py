@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bitplane_pack, bitplane_unpack, ops
 from repro.serve.batch import Ticket, _item_bytes
-from repro.transform.hierarchical import (level_map,
+from repro.transform.hierarchical import (level_map, recompose_hb_from,
+                                          scatter_recompose_from,
                                           scatter_recompose_from_batch)
 
 PADDED = (129, 513, 513)
@@ -30,6 +31,8 @@ LEVELS = 7
 FINEST = int(np.prod(PADDED)) - 65 * 257 * 257     # level-0 group count
 HBM_BYTES = int(15.75 * 2 ** 30)                   # usable HBM of one v5e
 SMOKE_BATCH = 2                                    # chip_smoke.py clients
+LADDER = (33, 129, 129)          # isabel.ladder's padded 25x125x125 grid
+LADDER_LEVELS = 5
 
 
 @pytest.fixture(scope="module")
@@ -127,12 +130,37 @@ def test_batched_recompose_fits_the_batchers_budget(one_chip):
     count = int(np.sum(level_map(PADDED, LEVELS) == 0))
     assert count == FINEST
     compiled = scatter_recompose_from_batch.lower(
-        _sds(one_chip, (SMOKE_BATCH, count), jnp.int64),
         _sds(one_chip, (SMOKE_BATCH, count), jnp.float64),
         PADDED, LEVELS, 0).compile()
     used = _device_bytes(compiled)
     item = Ticket(None, "recompose", None,
-                  (_nbytes((count,), np.int64), _nbytes((count,), np.float64),
-                   PADDED))
+                  (None, _nbytes((count,), np.float64), PADDED))
     assert used <= SMOKE_BATCH * _item_bytes(item)
     assert SMOKE_BATCH * _item_bytes(item) <= HBM_BYTES // 3
+
+
+@pytest.mark.parametrize("program,start", [
+    ("scatter_recompose_from", 0), ("scatter_recompose_from", 1),
+    ("scatter_recompose_from", 2),     # the device route of groups 0-2
+    ("scatter_recompose_from_batch", 0), ("scatter_recompose_from_batch", 1),
+    ("scatter_recompose_from_batch", 2),   # ... batched across clients
+    ("recompose_hb_from", 3), ("recompose_hb_from", 4),   # the host route
+])
+def test_hb_recompose_holds_no_scatter(one_chip, program, start):
+    """The recompose programs that isabel.ladder runs place and interleave
+    by slices and reshapes: a TPU scatter runs close to serially, and one
+    per level made these programs the device's largest cost."""
+    if program == "recompose_hb_from":
+        compiled = recompose_hb_from.lower(
+            _sds(one_chip, LADDER, jnp.float64), LADDER_LEVELS,
+            start).compile()
+    else:
+        count = int(np.sum(level_map(LADDER, LADDER_LEVELS) == start))
+        batched = program == "scatter_recompose_from_batch"
+        batch = (SMOKE_BATCH,) if batched else ()
+        fn = scatter_recompose_from_batch if batched else \
+            scatter_recompose_from
+        compiled = fn.lower(
+            _sds(one_chip, batch + (count,), jnp.float64), LADDER,
+            LADDER_LEVELS, start).compile()
+    assert "scatter(" not in compiled.as_text()
