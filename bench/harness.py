@@ -160,6 +160,15 @@ class Readings:
     def certified(self) -> List[check.Answer]:
         return [a for a in self.answers if a.certified]
 
+    def span_ms_per_answer(self, span: str) -> Optional[float]:
+        """Milliseconds of the program span ``span``'s self time in the
+        traced window, summed over threads, per answer; None with no
+        trace, no answer or no such span in the trace."""
+        if self.trace is None or not self.answers:
+            return None
+        s = self.trace.span_self_s.get(span)
+        return None if s is None else 1e3 * s / len(self.answers)
+
     def per_client(self) -> Dict[int, List[check.Answer]]:
         out: Dict[int, List[check.Answer]] = {}
         for a in self.answers:
@@ -467,10 +476,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         counters = server.metrics()
         server.close()
         summary = tracer.reduce() if tracer is not None else None
-        absent = tracer.absent if tracer is not None else []
-        if absent:
-            log(f"[bench] spans absent (no such function in the program): "
-                f"{absent}")
         t = time.perf_counter()
         checks = check.compare(sampler.samples(), answers, reference, fields,
                                cell.manifest["limits"])
@@ -481,11 +486,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(workdir, ignore_errors=True)
     readings = Readings(setup_s=setup_s, answers=answers, counters=counters,
                         trace=summary)
-    return _result(cell, readings, device, checks, trace, log, absent)
+    return _result(cell, readings, device, checks, trace, log)
 
 
 def _result(cell: Cell, readings: Readings, device: dict, checks: dict,
-            trace: bool, log, absent: Sequence[str] = ()) -> dict:
+            trace: bool, log) -> dict:
     # untraced runs report the end-to-end metrics (those with a bound),
     # traced runs the per-layer ones
     wanted = [m for m in cell.metrics if ("bound" in m) != trace]
@@ -503,13 +508,13 @@ def _result(cell: Cell, readings: Readings, device: dict, checks: dict,
         device["busy_s"] = s.busy_s
         device["window_s"] = s.window_s
         ops = sorted(s.programs.items(), key=lambda kv: -kv[1])[:10]
-        gaps = sorted(s.idle_by_span.items(), key=lambda kv: -kv[1])[:10]
+        gaps = sorted(s.idle_by_program_span.items(),
+                      key=lambda kv: -kv[1])[:10]
         out["breakdown"] = {"device_ops": [[k, v] for k, v in ops],
                             "idle_gaps": [[k, v] for k, v in gaps]}
         log(f"[bench] device programs (s): {dict(ops)}")
-        log(f"[bench] idle by host span (s): {dict(gaps)}")
-    if absent:
-        out["spans_absent"] = list(absent)
+        log(f"[bench] idle by program span (s): {dict(gaps)}")
+        log(f"[bench] program span self time (s): {s.span_self_s}")
     out["checks"] = checks
     return out
 
@@ -520,29 +525,22 @@ def _annotation(name: str):
 
 
 class _Tracer:
-    """The profiler over the window, with the layer spans on."""
+    """The profiler over the window; the program's own spans and the
+    harness's ``bench.*`` annotations are host events in its trace."""
 
     def __init__(self, workdir: str):
         self.logdir = str(Path(workdir) / "trace")
-        self._spans = None
-        self.absent: List[str] = []
 
     def start(self) -> None:
         import jax
-        from bench.spans import layer_spans
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
-        self._spans = layer_spans()
-        self.absent = self._spans.__enter__()
         jax.profiler.start_trace(self.logdir, profiler_options=opts)
 
     def stop(self) -> None:
         import jax
-        try:
-            jax.profiler.stop_trace()
-        finally:
-            self._spans.__exit__(None, None, None)
+        jax.profiler.stop_trace()
 
     def reduce(self):
         from bench import trace_reduce

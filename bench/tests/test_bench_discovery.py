@@ -14,7 +14,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import harness, traffic  # noqa: E402
+from bench import check, harness, trace_reduce, traffic  # noqa: E402
 
 
 @pytest.fixture
@@ -78,8 +78,81 @@ def test_new_config_mix_and_metric_are_found_by_name(checkout):
 
 
 def test_existing_cells_still_load(checkout):
-    for name in ("isabel.ladder",):
-        assert harness.load_cell(name, root=checkout).name == name
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for w in spec["workloads"]:
+        cell = harness.load_cell(w["name"], root=checkout)
+        assert cell.name == w["name"]
+        assert cell.manifest["name"] == w["config"]
+
+
+def test_a_copied_configuration_joins_as_new_files(checkout, tiny_cell):
+    """A configuration copied from isabel-velocity under a new name, with
+    its own traffic mix, cell and a metric that reads one program span,
+    all new files and new entries: the cell loads, the metric reads its
+    span, and the test size is the copy's own ``cpu_test``."""
+    src = ROOT / "bench" / "configs" / "isabel-velocity"
+    dst = checkout / "bench" / "configs" / "isabel-copy"
+    shutil.copytree(src, dst, ignore=shutil.ignore_patterns("__pycache__"))
+    manifest = json.loads((dst / "config.json").read_text())
+    manifest.update(name="isabel-copy", cpu_test={"shape": [5, 9, 9]})
+    (dst / "config.json").write_text(json.dumps(manifest))
+    mix = json.loads((ROOT / "bench" / "traffic" / "ladder.json")
+                     .read_text())
+    (checkout / "bench" / "traffic" / "copy_mix.json").write_text(
+        json.dumps(dict(mix, clients=1)))
+    (checkout / "bench" / "metrics" / "copy_refresh_ms.py").write_text(
+        "def read(r):\n"
+        "    return r.span_ms_per_answer('repro.reader.refresh')\n")
+    spec = json.loads((checkout / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "isabel-copy", "source": "a test",
+                            "file": "bench/configs/isabel-copy/config.json",
+                            "reduced": ["shape"], "why": "a test"})
+    spec["workloads"].append({"name": "copy.cell", "config": "isabel-copy",
+                              "traffic": "copy_mix", "chips": 1,
+                              "why": "a test"})
+    spec["per_layer"].append({"name": "copy_refresh_ms", "unit": "ms",
+                              "better": "lower", "source": "program_span",
+                              "layer": "reader and recompose",
+                              "moves": "answers_per_s",
+                              "workloads": ["copy.cell"]})
+    (checkout / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    cell = tiny_cell("copy.cell", root=checkout)
+    assert cell.config_dir == dst and cell.manifest["name"] == "isabel-copy"
+    assert cell.manifest["shape"] == [5, 9, 9]
+    assert cell.mix["clients"] == 1
+    names = [m["name"] for m in cell.metrics]
+    assert "copy_refresh_ms" in names and "answers_per_s" in names
+    assert "d2h_ms_per_answer" not in names      # listed for isabel.ladder
+    fields = cell.module("generate").generate(cell.manifest, 2**31 + 3)
+    assert all(f.shape == (5, 9, 9) for f in fields.values())
+    assert set(cell.module("reference").VARIABLES) == {"VTOT"}
+    read = harness.metric_reader("copy_refresh_ms", root=checkout)
+    summary = trace_reduce.TraceSummary(
+        window_s=1.0, busy_s=0.5, devices=1,
+        span_self_s={"repro.reader.refresh": 0.25})
+    answers = [check.Answer(client=0, session="s", qoi="VTOT", tau=0.1,
+                            submitted=0.0, done=1.0)] * 5
+    r = harness.Readings(setup_s=1.0, answers=answers, counters={},
+                         trace=summary)
+    assert read(r) == pytest.approx(50.0)
+    # the original's test size and manifest are its own
+    assert tiny_cell("isabel.ladder", root=checkout).manifest["shape"] == \
+        [9, 17, 17]
+
+
+def test_a_configuration_without_cpu_test_names_the_key(checkout,
+                                                        tiny_cell):
+    with pytest.raises(pytest.fail.Exception, match="'cpu_test'") as e:
+        tiny_cell("toy.cell", root=checkout)
+    assert "toy" in str(e.value)
+
+
+def test_the_run_never_reads_cpu_test():
+    """``cpu_test`` is test data: the entry point and the harness that a
+    run on the chip goes through never name it."""
+    for path in (ROOT / "bench" / "run.py", ROOT / "bench" / "harness.py"):
+        assert "cpu_test" not in path.read_text(), path
 
 
 def test_unknown_cell_is_refused(checkout):

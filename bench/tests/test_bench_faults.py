@@ -1,7 +1,8 @@
 """The check that decides ``correct`` fails when the timed path is broken
 underneath it: the control (every request answered a decade of tau looser)
 and each fault a served cell can have, planted at the server's public
-surface and driven through the rest of a run on the CPU at a tiny shape."""
+surface and driven through the rest of a run on the CPU at the
+configuration's CPU test size."""
 from concurrent.futures import Future
 import sys
 import time
@@ -15,41 +16,31 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import control, harness  # noqa: E402
 
-TINY = {"isabel-velocity": {"shape": [9, 17, 17]}}
 
-
-@pytest.fixture
-def on_cpu(monkeypatch):
-    monkeypatch.setattr(harness, "check_devices", lambda chips: {
-        "platform": "cpu", "kind": "cpu", "count": 1})
-    monkeypatch.setattr(harness, "use_compile_cache", lambda: "off")
-
-
-def run(name, plant=None, seed=2**31 + 29):
-    cell = harness.load_cell(name)
-    cell.manifest.update(TINY[cell.manifest["name"]])
+def run(cell, plant=None, seed=2**31 + 29):
     return harness.run_cell(cell, seed, 2.0, False,
                             t_start=time.perf_counter(), log=lambda s: None,
                             plant=plant)
 
 
 @pytest.mark.parametrize("seed", [2**31 + 29, 7])
-def test_control_looser_answers_are_not_correct(on_cpu, seed):
-    out = run("isabel.ladder", plant=control.plant_control, seed=seed)
+def test_control_looser_answers_are_not_correct(on_cpu, tiny_cell, seed):
+    out = run(tiny_cell("isabel.ladder"), plant=control.plant_control,
+              seed=seed)
     assert not out["correct"]
     assert out["checks"]["bound_over_tau"]["value"] > 1.0
     assert out["failed"] == 0          # it claims to certify every answer
 
 
-def test_stale_state_is_not_correct(on_cpu):
+def test_stale_state_is_not_correct(on_cpu, tiny_cell):
     """Each session's later answers keep its first answer's values, while
     the planes (and so the reported bound) move on."""
-    out = run("isabel.ladder", plant=control.plant_stale)
+    out = run(tiny_cell("isabel.ladder"), plant=control.plant_stale)
     assert not out["correct"]
     assert out["checks"]["err_over_bound"]["value"] > 1.0
 
 
-def test_answer_altered_where_produced_is_not_correct(on_cpu):
+def test_answer_altered_where_produced_is_not_correct(on_cpu, tiny_cell):
     """One value of each variable moved by far more than its bound."""
     def make(current, variables):
         def altered(v):
@@ -59,13 +50,13 @@ def test_answer_altered_where_produced_is_not_correct(on_cpu):
             data.flat[i] += np.sign(data.flat[i]) * max(100.0 * bound, 1.0)
             return data, bound
         return altered
-    out = run("isabel.ladder",
+    out = run(tiny_cell("isabel.ladder"),
               plant=lambda server: control.plant_values(server, make))
     assert not out["correct"]
     assert out["checks"]["err_over_bound"]["value"] > 1.0
 
 
-def test_uncertified_or_failed_answers_are_not_correct(on_cpu):
+def test_uncertified_or_failed_answers_are_not_correct(on_cpu, tiny_cell):
     calls = []
 
     def plant(server):
@@ -88,7 +79,7 @@ def test_uncertified_or_failed_answers_are_not_correct(on_cpu):
             inner.add_done_callback(relay)
             return outer
         server.submit = flaky
-    out = run("isabel.ladder", plant=plant)
+    out = run(tiny_cell("isabel.ladder"), plant=plant)
     assert not out["correct"]
     assert out["checks"]["unanswered"]["value"] > 0
     assert out["checks"]["uncertified"]["value"] > 0

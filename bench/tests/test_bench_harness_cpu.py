@@ -1,7 +1,6 @@
-"""Each cell's traffic through the harness's own pieces at a tiny shape on
-the CPU (the test steers the platform by replacing the harness's device
-check), and the entry point's refusals."""
-import functools
+"""Each cell's traffic through the harness's own pieces at its
+configuration's CPU test size (the test steers the platform by replacing
+the harness's device check), and the entry point's refusals."""
 import json
 import os
 import shutil
@@ -15,26 +14,16 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import harness, trace_reduce  # noqa: E402
+from bench import harness  # noqa: E402
+from repro import trace  # noqa: E402
 
-TINY = {"isabel-velocity": {"shape": [9, 17, 17]}}
 CELLS = [w["name"] for w in
          json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
-
-
-def tiny_cell(name):
-    cell = harness.load_cell(name)
-    cell.manifest.update(TINY[cell.manifest["name"]])
-    return cell
-
-
-@pytest.fixture
-def on_cpu(monkeypatch):
-    monkeypatch.setattr(harness, "check_devices", lambda chips: {
-        "platform": "cpu", "kind": "cpu", "count": 1})
-    # leave the test process's compile cache as it was
-    monkeypatch.setattr(harness, "use_compile_cache", lambda: "off")
+SPAN_METRICS = ("d2h_ms_per_answer", "device_wait_ms_per_answer",
+                "batch_window_ms_per_answer", "inflate_ms_per_answer",
+                "store_read_ms_per_answer", "contrib_sum_ms_per_answer",
+                "estimate_ms_per_answer")
 
 
 def run(cell, seed=2**31 + 11, seconds=2.0, trace=False, **kw):
@@ -44,7 +33,8 @@ def run(cell, seed=2**31 + 11, seconds=2.0, trace=False, **kw):
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_cell_runs_and_its_answers_pass_the_check(on_cpu, name, capsys):
+def test_cell_runs_and_its_answers_pass_the_check(on_cpu, tiny_cell, name,
+                                                  capsys):
     cell = tiny_cell(name)
     out = run(cell)
     assert list(out)[:5] == KEYS and list(out)[-1] == "checks"
@@ -61,26 +51,28 @@ def test_cell_runs_and_its_answers_pass_the_check(on_cpu, name, capsys):
     assert lines.err.strip().splitlines()[-1].startswith("check checked")
 
 
-def test_traced_run_reports_the_per_layer_metrics(on_cpu, monkeypatch):
-    cpu = functools.partial(
-        trace_reduce.reduce_trace, device_plane=lambda n: n == "/host:CPU",
-        busy_line=lambda n: n.startswith("tf_XLA"),
-        program_line=lambda n: n.startswith("tf_XLA"))
-    monkeypatch.setattr(trace_reduce, "reduce_trace", cpu)
+def test_traced_run_reports_the_per_layer_metrics(on_cpu, cpu_planes,
+                                                  tiny_cell, monkeypatch):
+    # the fused decode and its batcher, which the chip takes at the cell's
+    # size; the CPU test size's coefficient groups are below its threshold
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_decode_path", "fused")
     out = run(tiny_cell("isabel.ladder"), trace=True)
     assert out["correct"], out["checks"]
     for name in ("device_idle_share", "queue_wait_share",
                  "segment_cache_hit_share", "bytes_per_answer",
-                 "answer_p90_s.few"):
+                 "answer_p90_s.few") + SPAN_METRICS:
         assert name in out["metrics"], name
+    for name in SPAN_METRICS:
+        assert out["metrics"][name]["value"] >= 0, name
+        assert out["metrics"][name]["unit"] == "ms", name
     assert 0 <= out["metrics"]["device_idle_share"]["value"] <= 100
     assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
     assert out["breakdown"]["device_ops"] and out["breakdown"]["idle_gaps"]
     labels = {k for k, _ in out["breakdown"]["idle_gaps"]}
-    assert labels & {"bench.estimate", "bench.recompose_sum",
-                     "bench.reconstruct", "bench.fetch_decode_host",
-                     "bench.wait", "bench.serve", "bench.retrieve",
-                     "bench.eb_array", "bench.contrib_to_host"}
+    assert labels <= set(trace.SPANS) | {"none"}
+    assert labels & set(trace.SPANS)
+    assert "spans_absent" not in out
 
 
 def _entry(cwd, *extra):
@@ -104,21 +96,6 @@ def test_entry_point_refuses_a_checkout_without_the_program(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__"))
     p = _entry(tmp_path)
     assert p.returncode == 2 and p.stdout == ""
-
-
-def test_spans_skip_a_function_the_program_no_longer_has(monkeypatch):
-    from bench import spans
-    from repro.core.retrieval import _estimate
-    import repro.core.retrieval as retrieval
-    monkeypatch.setattr(spans, "LAYER_SPANS", (
-        ("repro.core.retrieval", None, "_estimate", "bench.estimate"),
-        ("repro.core.retrieval", None, "_gone", "bench.gone"),
-        ("repro.core.refactor", "NoSuchClass", "f", "bench.no_class"),
-        ("repro.no_such_module", None, "f", "bench.no_module")))
-    with spans.layer_spans() as absent:
-        assert absent == ["bench.gone", "bench.no_class", "bench.no_module"]
-        assert retrieval._estimate is not _estimate
-    assert retrieval._estimate is _estimate
 
 
 def test_batch_warm_up_skips_a_batcher_that_changed_its_calls():

@@ -36,7 +36,8 @@ def test_reference_matches_the_program_qoi(config):
 def test_ge_generator_is_the_program_generator():
     from repro.data.synthetic import ge_like_fields
     manifest = json.loads((CONFIGS / "ge-cfd" / "config.json").read_text())
-    tiny = dict(manifest, nodes=1000)
+    tiny = dict(manifest, **manifest["cpu_test"])
+    assert tiny["nodes"] == 1000
     mine = _module("ge-cfd", "generate").generate(tiny, 2**31 + 7)
     theirs = ge_like_fields(n=1000, seed=2**31 + 7)
     assert mine.keys() == theirs.keys()

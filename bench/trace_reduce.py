@@ -10,10 +10,14 @@ and returns a ``TraceSummary``:
 * device time per program: the summed durations of each program's
   executions (``XLA Modules`` events, named by the jitted function), with
   the execution id the runtime appends stripped;
-* idle time by what the host was doing: every gap between busy intervals
-  is given to the innermost benchmark span (a host event whose name starts
-  ``bench.``) that holds the gap's midpoint, and gap time is summed per
-  span name.
+* the program's spans (host events whose names start ``repro.``, named in
+  ``src/repro/trace.py``), read per thread line: each span's self time in
+  the window, the part of it that none of its children on the same line
+  covers, summed per name over threads;
+* idle time by what the program was doing: every gap between busy
+  intervals is given to the innermost program span (the shortest) open at
+  the gap's midpoint on any thread, or to "none", and gap time is summed
+  per span name.
 
 The device planes and their lines are chosen by predicates, so the same
 code reads a TPU trace (``/device:TPU:<n>`` planes) and, in the tests, a
@@ -28,11 +32,13 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = "bench."           # the harness's own (tools/span_breakdown.py)
+PROGRAM_PREFIX = "repro."        # the program's spans
 BUSY_LINES = ("XLA Ops",)
 PROGRAM_LINES = ("XLA Modules",)
 
 Interval = Tuple[float, float]
+Span = Tuple[float, float, str]
 
 
 def is_tpu_plane(name: str) -> bool:
@@ -45,7 +51,9 @@ class TraceSummary:
     busy_s: float                    # mean over device planes
     devices: int
     programs: Dict[str, float] = field(default_factory=dict)  # s, summed
-    idle_by_span: Dict[str, float] = field(default_factory=dict)  # s, mean
+    span_self_s: Dict[str, float] = field(default_factory=dict)  # s, summed
+    idle_by_program_span: Dict[str, float] = \
+        field(default_factory=dict)                              # s, mean
 
     def program_seconds(self, pattern: str) -> float:
         """Device seconds of every program whose name matches ``pattern``
@@ -99,12 +107,12 @@ def program_name(event_name: str) -> str:
 
 
 def label_gaps(idle: List[Interval],
-               spans: List[Tuple[float, float, str]]) -> Dict[str, float]:
+               spans: List[Span]) -> Dict[str, float]:
     """Seconds of idle time per innermost host span at each gap's
-    midpoint ("none" where no benchmark span is open)."""
+    midpoint ("none" where no span is open)."""
     out: Dict[str, float] = {}
     spans = sorted(spans)
-    active: List[Tuple[float, float, str]] = []
+    active: List[Span] = []
     i = 0
     for s, e in sorted(idle, key=lambda g: g[0] + g[1]):   # by midpoint
         mid = 0.5 * (s + e)
@@ -118,6 +126,32 @@ def label_gaps(idle: List[Interval],
     return out
 
 
+def self_times(lines: Iterable[List[Span]], lo: float,
+               hi: float) -> Dict[str, float]:
+    """Seconds per span name of each span's part of [lo, hi] that none of
+    its children covers; ``lines`` holds the spans of one thread each, so
+    a span's parent is the innermost span of its line that encloses it."""
+    out: Dict[str, float] = {}
+    for line in lines:
+        stack: List[list] = []         # open [start, end, name, children]
+        closed: List[list] = []
+        for s, e, name in sorted(line, key=lambda sp: (sp[0], -sp[1])):
+            while stack and stack[-1][1] <= s:
+                closed.append(stack.pop())
+            if stack:
+                stack[-1][3].append((s, e))
+            stack.append([s, e, name, []])
+        closed.extend(stack)
+        for s, e, name, children in closed:
+            own = clip([(s, e)], lo, hi)
+            if not own:
+                continue
+            a, b = own[0]
+            covered = sum(y - x for x, y in union(clip(children, a, b)))
+            out[name] = out.get(name, 0.0) + (b - a) - covered
+    return out
+
+
 def reduce_trace(path: str,
                  device_plane: Callable[[str], bool] = is_tpu_plane,
                  busy_line: Callable[[str], bool] = lambda n: n in BUSY_LINES,
@@ -128,25 +162,29 @@ def reduce_trace(path: str,
     from jax.profiler import ProfileData
     profile = ProfileData.from_file(path)
     window: Optional[Interval] = None
-    spans: List[Tuple[float, float, str]] = []
+    lines: List[List[Span]] = []       # the program's spans, per thread
     devices = []
     for plane in profile.planes:
         if device_plane(plane.name):
             devices.append(plane)
         for line in plane.lines:
+            spans: List[Span] = []
             for ev in line.events:
-                if not ev.name.startswith(SPAN_PREFIX):
-                    continue
-                iv = (ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9)
-                if ev.name == window_span:
-                    window = iv
-                else:
-                    spans.append((iv[0], iv[1], ev.name))
+                name = ev.name
+                if name.startswith(PROGRAM_PREFIX):
+                    spans.append((ev.start_ns * 1e-9,
+                                  (ev.start_ns + ev.duration_ns) * 1e-9, name))
+                elif name == window_span:
+                    window = (ev.start_ns * 1e-9,
+                              (ev.start_ns + ev.duration_ns) * 1e-9)
+            if spans:
+                lines.append(spans)
     if window is None:
         raise ValueError(f"no {window_span!r} span in {path}")
     if not devices:
         raise ValueError(f"no device plane in {path}")
     lo, hi = window
+    flat = [sp for line in lines for sp in line]
     busy_total, programs, idle_total = 0.0, {}, {}
     for plane in devices:
         busy: List[Interval] = []
@@ -167,9 +205,11 @@ def reduce_trace(path: str,
                         + iv[0][1] - iv[0][0]
         merged = union(busy)
         busy_total += sum(e - s for s, e in merged)
-        for k, v in label_gaps(gaps(merged, lo, hi), spans).items():
+        for k, v in label_gaps(gaps(merged, lo, hi), flat).items():
             idle_total[k] = idle_total.get(k, 0.0) + v
     n = len(devices)
     return TraceSummary(window_s=hi - lo, busy_s=busy_total / n, devices=n,
                         programs=programs,
-                        idle_by_span={k: v / n for k, v in idle_total.items()})
+                        span_self_s=self_times(lines, lo, hi),
+                        idle_by_program_span={k: v / n
+                                              for k, v in idle_total.items()})
