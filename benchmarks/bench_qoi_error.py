@@ -9,8 +9,7 @@ from __future__ import annotations
 
 
 from benchmarks.common import actual_qoi_error, timed
-from repro.core import ge
-from repro.core.qoi import Prod, Var
+from repro.core import ge, s3d
 from repro.core.refactor import refactor_variables
 from repro.core.retrieval import QoIRequest, retrieve_qoi_controlled
 from repro.data.synthetic import ge_like_fields, nyx_like_fields, s3d_like_fields
@@ -55,11 +54,8 @@ def run():
     rows += _sweep(hurricane, {"VTOT": ge.v_total()},
                    mask_zero_velocity=False, label="Hurricane")
 
-    s3d = s3d_like_fields(shape=(33, 17, 17))
-    sub = {k: s3d[k] for k in ("x0", "x1", "x3", "x4", "x5")}
-    qois = {"x1x3": Prod(Var("x1"), Var("x3")),
-            "x0x4": Prod(Var("x0"), Var("x4")),
-            "x1x5": Prod(Var("x1"), Var("x5")),
-            "x3x4": Prod(Var("x3"), Var("x4"))}
-    rows += _sweep(sub, qois, mask_zero_velocity=False, label="S3D")
+    s3d_fields = s3d_like_fields(shape=(33, 17, 17))
+    sub = {k: s3d_fields[k] for k in ("x0", "x1", "x3", "x4", "x5")}
+    rows += _sweep(sub, s3d.all_qois(), mask_zero_velocity=False,
+                   label="S3D")
     return rows

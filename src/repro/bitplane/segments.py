@@ -180,6 +180,8 @@ class LevelStream:
                                                   state=state)
             self.fetched = got
             self.bytes_fetched += new_bytes
+            if self.xfer_stats is not None:
+                self.xfer_stats.note(planes=len(blobs))
             self._values = None
             self._values_dev = None
         if err is not None:

@@ -29,7 +29,8 @@ import numpy as np
 
 from repro.core.qoi import Expr
 from repro.core.refactor import VarAvailability
-from repro.trace import ESTIMATE, TransferStats, note_h2d, span, to_host
+from repro.trace import (ESTIMATE, REASSIGN, TransferStats, note_h2d,
+                         span, to_host)
 
 REDUCTION_FACTOR = 1.5          # c in Alg 4
 MIN_REL_EPS = 2.0 ** -60        # full-fidelity floor
@@ -199,75 +200,79 @@ def retrieve_qoi_controlled(session,
             break
 
         # -- reassign_eb (Alg 4): tighten on the worst point
-        qname, idx, _ = worst
-        req = next(r for r in requests if r.name == qname)
-        involved = sorted(req.expr.variables())
-        pt_vals = {v: values[v].ravel()[idx] for v in involved}
-        # a pinned variable's bound cannot drop below what it achieved —
-        # seeding its ladder with the (unreachable) requested eps would
-        # predict tightenings the reconstruct pass can never deliver and
-        # spin the reassign loop until max_iters
-        pt_ebs = {v: achieved[v] if v in pinned_vars
-                  else min(achieved[v], eps[v]) for v in involved}
-        # honour exact (masked) points
-        for v in involved:
-            pt_ebs[v] = float(eb_arrays[v].ravel()[idx]) if \
-                eb_arrays[v].ravel()[idx] == 0.0 else pt_ebs[v]
-        # Evaluate the whole geometric eps-ladder of candidate bound states
-        # in ONE batched _estimate call (§Perf) — the legacy loop dispatched
-        # up to LADDER_STEPS sequential scalar-jit evaluations.  State t is
-        # exactly what t reduction rounds of the sequential loop produce
-        # (cumulative division, per-variable floor clamp, frozen once at or
-        # below the floor — masked points enter at 0 and stay there).
-        ladders: Dict[str, np.ndarray] = {}
-        for v in involved:
-            lad = np.empty(LADDER_STEPS + 1, dtype=np.float64)
-            cur = pt_ebs[v]
-            lad[0] = cur
-            for t in range(1, LADDER_STEPS + 1):
-                if cur > floors[v]:
-                    cur = max(cur / reduction, floors[v])
-                lad[t] = cur
-            ladders[v] = lad
-        # -- async segment prefetch: reassign always lands at ladder state
-        # t_star >= 1 (state 0 is the current, still-violating bound), so the
-        # planes for ladder[depth=1] are a guaranteed prefix of the next
-        # round's fetch.  Hand these predicted next-eps to the fetcher NOW so
-        # store-backed sessions move segments in the background while the
-        # batched ladder estimate below (and the next estimator round) run.
-        # Depths > 1 hide more latency but may speculate past t_star.
-        depth = int(np.clip(getattr(session, "prefetch_depth", 1),
-                            1, LADDER_STEPS))
-        if prefetch is not None:
+        with span(REASSIGN):
+            qname, idx, _ = worst
+            req = next(r for r in requests if r.name == qname)
+            involved = sorted(req.expr.variables())
+            pt_vals = {v: values[v].ravel()[idx] for v in involved}
+            # a pinned variable's bound cannot drop below what it achieved —
+            # seeding its ladder with the (unreachable) requested eps would
+            # predict tightenings the reconstruct pass can never deliver and
+            # spin the reassign loop until max_iters
+            pt_ebs = {v: achieved[v] if v in pinned_vars
+                      else min(achieved[v], eps[v]) for v in involved}
+            # honour exact (masked) points
             for v in involved:
-                predicted = float(ladders[v][depth])
-                if predicted > 0.0:
-                    prefetch(v, min(eps[v], predicted), certain=False)
-        _, pb = _estimate(
-            req.expr,
-            {v: np.full(LADDER_STEPS, pt_vals[v]) for v in involved},
-            {v: ladders[v][:LADDER_STEPS] for v in involved}, xfer)
-        ok = np.asarray(pb) <= tau_abs[qname]
-        progressable = np.zeros(LADDER_STEPS, dtype=bool)
-        for v in involved:
-            progressable |= ladders[v][:LADDER_STEPS] > floors[v]
-        frozen = ~progressable
-        at_floor = False
-        if ok.any():
-            t_star = int(np.argmax(ok))       # first state meeting tau
-        elif frozen.any():
-            t_star = int(np.argmax(frozen))   # sequential loop stops here
-            at_floor = True
-        else:
-            t_star = LADDER_STEPS             # exhausted without converging
-        pt_ebs = {v: float(ladders[v][t_star]) for v in involved}
-        for v in involved:
-            eps[v] = min(eps[v], pt_ebs[v]) if pt_ebs[v] > 0 else eps[v]
-        # -- the landing state is now exact: prefetch the full next-round
-        # plane set so transport overlaps the remaining bookkeeping and the
-        # per-variable decode/recompose of the next reconstruct pass.
-        for v in involved:
-            hint(v, eps[v])
+                pt_ebs[v] = float(eb_arrays[v].ravel()[idx]) if \
+                    eb_arrays[v].ravel()[idx] == 0.0 else pt_ebs[v]
+            # Evaluate the whole geometric eps-ladder of candidate bound
+            # states in ONE batched _estimate call (§Perf) — the legacy loop
+            # dispatched up to LADDER_STEPS sequential scalar-jit
+            # evaluations.  State t is exactly what t reduction rounds of
+            # the sequential loop produce (cumulative division, per-variable
+            # floor clamp, frozen once at or below the floor — masked points
+            # enter at 0 and stay there).
+            ladders: Dict[str, np.ndarray] = {}
+            for v in involved:
+                lad = np.empty(LADDER_STEPS + 1, dtype=np.float64)
+                cur = pt_ebs[v]
+                lad[0] = cur
+                for t in range(1, LADDER_STEPS + 1):
+                    if cur > floors[v]:
+                        cur = max(cur / reduction, floors[v])
+                    lad[t] = cur
+                ladders[v] = lad
+            # -- async segment prefetch: reassign always lands at ladder
+            # state t_star >= 1 (state 0 is the current, still-violating
+            # bound), so the planes for ladder[depth=1] are a guaranteed
+            # prefix of the next round's fetch.  Hand these predicted
+            # next-eps to the fetcher NOW so store-backed sessions move
+            # segments in the background while the batched ladder estimate
+            # below (and the next estimator round) run.  Depths > 1 hide
+            # more latency but may speculate past t_star.
+            depth = int(np.clip(getattr(session, "prefetch_depth", 1),
+                                1, LADDER_STEPS))
+            if prefetch is not None:
+                for v in involved:
+                    predicted = float(ladders[v][depth])
+                    if predicted > 0.0:
+                        prefetch(v, min(eps[v], predicted), certain=False)
+            _, pb = _estimate(
+                req.expr,
+                {v: np.full(LADDER_STEPS, pt_vals[v]) for v in involved},
+                {v: ladders[v][:LADDER_STEPS] for v in involved}, xfer)
+            ok = np.asarray(pb) <= tau_abs[qname]
+            progressable = np.zeros(LADDER_STEPS, dtype=bool)
+            for v in involved:
+                progressable |= ladders[v][:LADDER_STEPS] > floors[v]
+            frozen = ~progressable
+            at_floor = False
+            if ok.any():
+                t_star = int(np.argmax(ok))       # first state meeting tau
+            elif frozen.any():
+                t_star = int(np.argmax(frozen))   # sequential loop stops here
+                at_floor = True
+            else:
+                t_star = LADDER_STEPS             # exhausted, not converged
+            pt_ebs = {v: float(ladders[v][t_star]) for v in involved}
+            for v in involved:
+                eps[v] = min(eps[v], pt_ebs[v]) if pt_ebs[v] > 0 else eps[v]
+            # -- the landing state is now exact: prefetch the full
+            # next-round plane set so transport overlaps the remaining
+            # bookkeeping and the per-variable decode/recompose of the next
+            # reconstruct pass.
+            for v in involved:
+                hint(v, eps[v])
         if at_floor:
             # full fidelity reached and still unbounded -> retrieve all and stop
             for v in involved:

@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.bitplane import codecs as plane_codecs
 from repro.compile_cache import use_checkout_cache
-from repro.core import ge
+from repro.core import catalog
 from repro.core.refactor import ContribStats, refactor_variables
 from repro.core.retrieval import QoIRequest, retrieve_qoi_controlled
 from repro.data.synthetic import ge_like_fields
@@ -90,7 +90,11 @@ class RetrievalServer:
     everything segments under pressure instead of churning the cache.
     ``workers`` / ``queue_depth`` size the worker pool and its shedding
     high-water mark; ``coalesce=False`` disables cross-session
-    single-flight (benchmark baseline)."""
+    single-flight (benchmark baseline).
+
+    The server offers every catalog QoI whose variables its archive holds
+    (``qois``, from ``repro.core.catalog``) and refuses a request for any
+    other with a ValueError before any retrieval."""
 
     def __init__(self, fields, method: str = "hb",
                  store_path: Optional[str] = None,
@@ -148,7 +152,7 @@ class RetrievalServer:
         self.sessions: Dict[str, object] = {}
         self._sessions_mu = threading.Lock()
         self.refactor_s = time.time() - t0
-        self.qois = ge.all_qois()
+        self.qois = catalog.offered(self.archive.variables)
         self.plane = ServePlane(self._handle, workers=workers,
                                 queue_depth=queue_depth,
                                 session_key=lambda req: req.client)
@@ -171,15 +175,25 @@ class RetrievalServer:
                 self.sessions[client] = session
         return session
 
+    def _requests(self, req: Request) -> List[QoIRequest]:
+        """The retrieval requests of ``req``: a ValueError, before any
+        retrieval, where it names a QoI this server does not offer."""
+        unknown = [q for q in req.qois if q not in self.qois]
+        if unknown:
+            raise ValueError(f"QoI {', '.join(map(repr, unknown))} is not "
+                             f"offered on this archive; it offers "
+                             f"{', '.join(sorted(self.qois))}")
+        return [QoIRequest(q, self.qois[q], req.tau) for q in req.qois]
+
     def _handle(self, req: Request):
         """One request, run inline on the calling thread (the worker body;
         also the sequential baseline the concurrency bench compares
         against).  Per-session serialization is the ServePlane's job."""
+        reqs = self._requests(req)
         with span(REQUEST, client=req.client, seq=next(self._seq),
                   tau=req.tau):
             session = self._session(req.client)
             before = session.bytes_retrieved
-            reqs = [QoIRequest(q, self.qois[q], req.tau) for q in req.qois]
             t0 = time.time()
             res = retrieve_qoi_controlled(session, reqs)
         with self._rounds_mu:
@@ -203,8 +217,10 @@ class RetrievalServer:
         return self.plane.handle(req)
 
     def submit(self, req: Request):
-        """Async entry point: a Future, or ServerOverloadedError at the
-        door when the pending queue is past the high-water mark."""
+        """Async entry point: a Future, or at the door a ValueError for a
+        QoI not offered or ServerOverloadedError when the pending queue is
+        past the high-water mark."""
+        self._requests(req)
         return self.plane.submit(req)
 
     # -- observability -------------------------------------------------------
@@ -215,14 +231,16 @@ class RetrievalServer:
     def metrics(self) -> Dict[str, float]:
         """One flat counter dict for /metrics: pool, coalescer, budget
         pool, decode batcher, segment cache, fetcher (transport + contrib +
-        fault counters), host-device transfers, Alg-2 rounds and the
-        backend compiles since this server was made — everything a
-        dashboard needs to see a multi-tenant server breathe."""
+        fault counters), host-device transfers, bit-planes taken, Alg-2
+        rounds and the backend compiles since this server was made —
+        everything a dashboard needs to see a multi-tenant server
+        breathe."""
         out = {f"serve_{k}": v for k, v in self.plane.metrics().items()}
         with self._sessions_mu:
             out["serve_sessions_sticky"] = float(len(self.sessions))
         for k, v in self.xfer.as_dict().items():
             out[f"xfer_{k}"] = v
+        out["retrieval_planes_total"] = float(self.xfer.planes)
         with self._rounds_mu:
             out["retrieval_iterations_total"] = float(self._rounds)
         n, s = compiles().snapshot()
@@ -412,7 +430,7 @@ def main(argv=None) -> int:
 
     rng = np.random.default_rng(0)
     clients = [f"client{i}" for i in range(4)]
-    qoi_names = list(ge.all_qois())
+    qoi_names = list(server.qois)
     requests = [Request(client=str(rng.choice(clients)),
                         qois=list(rng.choice(qoi_names,
                                              size=rng.integers(1, 4),

@@ -16,6 +16,7 @@ The span names, one table for the code and for PERF.md:
 ``repro.request``               one request on its worker thread
 ``repro.retrieval.reconstruct`` one variable's progressive refresh
 ``repro.retrieval.estimate``    QoI estimate: upload, dispatch, bounds
+``repro.retrieval.reassign``    Alg-4 step: ladder, point estimate, landing
 ``repro.store.read``            store read + crc32c check (not cache hits)
 ``repro.codec.inflate``         host entropy decode of fetched planes
 ``repro.batch.window``          waiting for a decode batch to fill / drain
@@ -27,8 +28,9 @@ The span names, one table for the code and for PERF.md:
 
 Counters follow the serve plane's pattern, one lock-guarded stats object
 per subsystem merged by ``RetrievalServer.metrics()``: ``TransferStats``
-counts bytes across the host-device boundary, and ``compiles()`` is the
-process's count of backend compiles (a ``jax.monitoring`` listener).
+counts bytes across the host-device boundary and the bit-planes the
+readers take, and ``compiles()`` is the process's count of backend
+compiles (a ``jax.monitoring`` listener).
 """
 from __future__ import annotations
 
@@ -43,6 +45,7 @@ from jax.profiler import TraceAnnotation
 REQUEST = "repro.request"
 RECONSTRUCT = "repro.retrieval.reconstruct"
 ESTIMATE = "repro.retrieval.estimate"
+REASSIGN = "repro.retrieval.reassign"
 STORE_READ = "repro.store.read"
 INFLATE = "repro.codec.inflate"
 BATCH_WINDOW = "repro.batch.window"
@@ -50,8 +53,8 @@ BATCH_FLUSH = "repro.batch.flush"
 READER_REFRESH = "repro.reader.refresh"
 DEVICE_WAIT = "repro.device.wait"
 TRANSFER_D2H = "repro.transfer.d2h"
-SPANS = (REQUEST, RECONSTRUCT, ESTIMATE, STORE_READ, INFLATE, BATCH_WINDOW,
-         BATCH_FLUSH, READER_REFRESH, DEVICE_WAIT, TRANSFER_D2H)
+SPANS = (REQUEST, RECONSTRUCT, ESTIMATE, REASSIGN, STORE_READ, INFLATE,
+         BATCH_WINDOW, BATCH_FLUSH, READER_REFRESH, DEVICE_WAIT, TRANSFER_D2H)
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
@@ -66,18 +69,23 @@ def span(name: str, **meta) -> TraceAnnotation:
 class TransferStats:
     """Bytes across the host-device boundary on the retrieval path:
     every device-to-host copy made through ``to_host``, and every host
-    array handed to the device (counted by the caller with ``note_h2d``)."""
+    array handed to the device (counted by the caller with ``note_h2d``);
+    and the bit-planes the readers newly take, summed over variables and
+    coefficient groups (counted where a ``LevelStream`` takes them)."""
     d2h_bytes: int = 0
     h2d_bytes: int = 0
+    planes: int = 0
     _mu: threading.Lock = field(default_factory=threading.Lock,
                                 repr=False, compare=False)
 
-    def note(self, d2h: int = 0, h2d: int = 0) -> None:
+    def note(self, d2h: int = 0, h2d: int = 0, planes: int = 0) -> None:
         with self._mu:
             self.d2h_bytes += int(d2h)
             self.h2d_bytes += int(h2d)
+            self.planes += int(planes)
 
     def as_dict(self) -> Dict[str, float]:
+        """The byte counts; ``planes`` is read on its own."""
         with self._mu:
             return {"d2h_bytes_total": float(self.d2h_bytes),
                     "h2d_bytes_total": float(self.h2d_bytes)}

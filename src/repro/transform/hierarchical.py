@@ -195,12 +195,16 @@ def _recompose_steps(cur: Array, views: List[Array]) -> Array:
 
 
 def _recompose_field(c: Array, start: int) -> Array:
-    """Steps start..0 of a whole coefficient field (none for start < 0)."""
+    """Steps start..0 of a whole coefficient field (none for start < 0).
+    Each stride-2^l view is one stride-2 slice of the next finer view: a
+    single slice of stride 128 over a 129-wide axis (a 129^3 grid's base
+    step) runs out of the v5e's scoped vmem when it compiles."""
     if start < 0:
         return c
-    return _recompose_steps(_strided(c, 2 << start),
-                            [_strided(c, 1 << l)
-                             for l in range(start, -1, -1)])
+    views = [c]
+    for _ in range(start + 1):
+        views.append(_strided(views[-1], 2))
+    return _recompose_steps(views[-1], views[-2::-1])
 
 
 @functools.partial(jax.jit, static_argnums=(1,))

@@ -3,8 +3,7 @@ on every representation, byte accounting is monotone, masks work."""
 import numpy as np
 import pytest
 
-from repro.core import ge
-from repro.core.qoi import Prod, Var
+from repro.core import ge, s3d
 from repro.core.refactor import refactor_variables
 from repro.core.retrieval import QoIRequest, assign_eb, retrieve_qoi_controlled
 from repro.data.synthetic import ge_like_fields, s3d_like_fields
@@ -86,8 +85,7 @@ def test_s3d_multiplication_qois():
     sub = {k: fields[k] for k in ("x0", "x1", "x3", "x4")}
     arch = refactor_variables(sub, method="hb", nbits=40,
                               mask_zero_velocity=False)
-    qois = {"x1x3": Prod(Var("x1"), Var("x3")),
-            "x0x4": Prod(Var("x0"), Var("x4"))}
+    qois = {k: s3d.all_qois()[k] for k in ("x1x3", "x0x4")}
     reqs = [QoIRequest(k, e, 1e-4) for k, e in qois.items()]
     res = retrieve_qoi_controlled(arch.open(), reqs)
     assert res.converged

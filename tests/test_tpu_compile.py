@@ -5,7 +5,8 @@ Each test compiles for one chip of a described ``v5e:2x2`` topology (the
 TPU compiler is installed even where no TPU is attached): what Mosaic or
 XLA would refuse on the chip fails here.  Sizes are ISABEL's SDRBench
 100x500x500 field padded by the transform to 129x513x513, whose finest
-coefficient group is the largest array the decode path sees.  The
+coefficient group is the largest array the decode path sees; the
+recompose programs are compiled at the benchmark cells' grids as well.  The
 topology is described inside a fixture, never at import, so every xdist
 worker collects the same tests and only the worker running this file loads
 the TPU library.
@@ -33,6 +34,8 @@ HBM_BYTES = int(15.75 * 2 ** 30)                   # usable HBM of one v5e
 SMOKE_BATCH = 2                                    # chip_smoke.py clients
 LADDER = (33, 129, 129)          # isabel.ladder's padded 25x125x125 grid
 LADDER_LEVELS = 5
+S3D = (129, 129, 129)            # s3d.ladder's padded 125^3 grid
+S3D_LEVELS = 7
 
 
 @pytest.fixture(scope="module")
@@ -164,3 +167,34 @@ def test_hb_recompose_holds_no_scatter(one_chip, program, start):
             _sds(one_chip, batch + (count,), jnp.float64), LADDER,
             LADDER_LEVELS, start).compile()
     assert "scatter(" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("program,start", [
+    ("scatter_recompose_from", 3),      # the device route of groups 0-3
+    ("scatter_recompose_from_batch", 0), ("scatter_recompose_from_batch", 1),
+    ("scatter_recompose_from_batch", 2), ("scatter_recompose_from_batch", 3),
+    ("recompose_hb_from", 4), ("recompose_hb_from", 5),
+    ("recompose_hb_from", 6),           # the host route, base group included
+])
+def test_s3d_recompose_compiles_without_scatter(one_chip, program, start):
+    """s3d.ladder's cubic grid has two more levels than isabel.ladder's:
+    its recompose programs compile at every start the reader takes there,
+    and hold no scatter either.  Groups 0-3 hold at least
+    ``ops.FUSED_MIN_COUNT`` coefficients, so they take the device route."""
+    counts = [int(np.sum(level_map(S3D, S3D_LEVELS) == s))
+              for s in range(S3D_LEVELS)]
+    assert [c >= ops.FUSED_MIN_COUNT for c in counts] == \
+        [True] * 4 + [False] * (S3D_LEVELS - 4)
+    if program == "recompose_hb_from":
+        compiled = recompose_hb_from.lower(
+            _sds(one_chip, S3D, jnp.float64), S3D_LEVELS, start).compile()
+    else:
+        batched = program == "scatter_recompose_from_batch"
+        batch = (SMOKE_BATCH,) if batched else ()
+        fn = scatter_recompose_from_batch if batched else \
+            scatter_recompose_from
+        compiled = fn.lower(
+            _sds(one_chip, batch + (counts[start],), jnp.float64), S3D,
+            S3D_LEVELS, start).compile()
+    assert "scatter(" not in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES

@@ -51,7 +51,7 @@ def test_spans_record_nothing_without_a_profiler_and_counters_count(
 
 
 def test_span_names_are_one_table():
-    assert len(set(trace.SPANS)) == len(trace.SPANS) == 10
+    assert len(set(trace.SPANS)) == len(trace.SPANS) == 11
     assert all(name.startswith("repro.") for name in trace.SPANS)
 
 
@@ -141,3 +141,42 @@ def test_estimator_program_has_a_stable_name():
     fn = retrieval._JIT_CACHE[(expr, ("a", "b"), ((5,), (5,)))]
     text = fn.lower(values, ebs).as_text()
     assert "jit__qoi_estimate" in text and "lambda" not in text
+
+
+def test_reassign_span_and_planes_counter(tmp_path, monkeypatch):
+    """A species product asked at a tight tau takes the Alg-4 step, whose
+    span is in the trace, and ``retrieval_planes_total`` rises by exactly
+    the planes the session's streams took."""
+    from repro.data.synthetic import s3d_like_fields
+    taken = []
+    fetch = segments.LevelStream.fetch_to_planes
+
+    def spy_fetch(stream, k):
+        before = stream.fetched
+        out = fetch(stream, k)
+        taken.append(stream.fetched - before)
+        return out
+    monkeypatch.setattr(segments.LevelStream, "fetch_to_planes", spy_fetch)
+    s3d = s3d_like_fields(shape=SHAPE, seed=5)
+    fields = {k: s3d[k] for k in ("x0", "x1", "x3", "x4", "x5")}
+    server = serve.RetrievalServer(fields, store_path=str(
+        tmp_path / "s.prs"), workers=1)
+    try:
+        server.handle(serve.Request(client="c0", qois=["x1x3"], tau=1e-2))
+        first = server.metrics()["retrieval_planes_total"]
+        assert first == sum(taken) > 0
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path / "t"), profiler_options=opts)
+        try:
+            out = server.handle(serve.Request(client="c0", qois=["x1x3"],
+                                              tau=1e-6))
+        finally:
+            jax.profiler.stop_trace()
+        m = server.metrics()
+    finally:
+        server.close()
+    assert out["guaranteed"]
+    assert m["retrieval_planes_total"] == sum(taken) > first
+    events = _repro_events(str(tmp_path / "t"))
+    assert trace.REASSIGN in events and trace.ESTIMATE in events
